@@ -21,8 +21,9 @@ analysis" for the per-kernel instantiations):
   12 MB — ¾ of a core, leaving headroom for pipeline bookkeeping and the
   compiler's own temporaries.
 * **SMEM**.  Scalar-prefetch operands (``PrefetchScalarGridSpec``) live in
-  scalar memory, which is far smaller than VMEM; the (W_s, A) active-topic
-  table is the dominant consumer at ~512 KB for W_s=8k, A=16.  The default
+  scalar memory, which is far smaller than VMEM.  A 2-D table is padded to
+  the (8, 128) tile, so the kernels pass the (W_s, A) active-topic table
+  flattened: ~512 KB for W_s=8k, A=16, the dominant consumer.  The default
   budget is 1 MB.
 * **Tile sizing** for the grid-over-token-blocks kernels
   (``foem_estep``/``topk_estep``) uses ``ESTEP_TILE_BUDGET`` (two thirds of
@@ -114,7 +115,14 @@ class Scalar:
     dtype_bytes: int = 4
 
     def smem_bytes(self) -> int:
-        return math.prod(self.shape) * self.dtype_bytes
+        """Scalar-memory bytes: a 2-D table pads its minor dim to 128 words
+        and its second-minor to 8 rows, so a (W_s, 16) table costs 8x its
+        data; the kernels flatten large tables to 1-D."""
+        shape = self.shape
+        if len(shape) >= 2:
+            shape = (*shape[:-2], round_up(shape[-2], SUBLANE),
+                     round_up(shape[-1], LANE))
+        return math.prod(shape) * self.dtype_bytes
 
 
 @dataclasses.dataclass(frozen=True)

@@ -7,8 +7,8 @@ and the ROADMAP's W_s=8k/K=128 target — and reports, per (kernel, cell):
 * VMEM live-set fit (carried + scratch + double-buffered per-column
   blocks) with the dominating operand,
 * SMEM scalar-prefetch fit,
-* lane/sublane alignment of every block (last dim ≡ 0 mod 128 or 1 when
-  compiled; second-minor ≡ 0 mod 8 or 1),
+* lane/sublane alignment of every block (each of the last two dims a
+  multiple of the (8, 128) tile or equal to the operand's dim),
 * ``input_output_aliases`` shape/dtype consistency and donation coverage
   (every VMEM-carried output must be donated — a carried output without
   an alias would silently double the HBM footprint),
@@ -102,26 +102,32 @@ class CheckReport:
 
 
 def _alignment_errors(spec: LaunchSpec, lane_align: int = bm.LANE) -> List[str]:
+    """The TPU block tiling rule for every BlockSpec operand.
+
+    Each of a block's last two dims must be a multiple of the tile — 128
+    lanes, 8 sublanes — or equal the operand's own dim there.  A minor dim
+    of 1 is legal only when the operand's minor dim is 1 too: a (D, 1)
+    column block over a (D, L) array is refused by the compiler.
+    """
     if lane_align <= 1:
         # interpret-mode layout: blocks are plain arrays, no (8, 128)
         # tiling exists, so lane/sublane residues are meaningless
         return []
     errs = []
-    for b in spec.inputs + spec.outputs + spec.scratch:
-        shape = b.block_shape
+    for b in spec.inputs + spec.outputs:
+        shape, full = tuple(b.block_shape), tuple(b.full_shape)
         if len(shape) < 2:
-            shape = (1,) + tuple(shape)
-        lanes, subl = shape[-1], shape[-2]
-        if lanes != 1 and lanes % bm.LANE:
-            errs.append(
-                f"{spec.kernel}/{b.name}: minor dim {lanes} is neither 1 "
-                f"nor a multiple of the {bm.LANE}-lane tile"
-            )
-        if subl != 1 and subl % bm.SUBLANE:
-            errs.append(
-                f"{spec.kernel}/{b.name}: second-minor dim {subl} is "
-                f"neither 1 nor a multiple of the {bm.SUBLANE}-sublane tile"
-            )
+            shape, full = (1,) + shape, (1,) + full
+        for axis, tile, what in ((-1, bm.LANE, "minor"),
+                                 (-2, bm.SUBLANE, "second-minor")):
+            dim = shape[axis]
+            if dim % tile and dim != full[axis]:
+                errs.append(
+                    f"{spec.kernel}/{b.name}: {what} block dim {dim} is "
+                    f"neither a multiple of the {tile}-"
+                    f"{'lane' if tile == bm.LANE else 'sublane'} tile nor "
+                    f"the operand's dim {full[axis]}"
+                )
     return errs
 
 

@@ -58,6 +58,12 @@ def _pads(cell: Cell, lane_align: int) -> Tuple[int, int]:
     return cell.padded(lane_align)
 
 
+def _column(name: str, Dp: int, L: int) -> Block:
+    """A per-token (D, L) operand laid out by column, (L, Dp, 1), walked
+    one (1, Dp, 1) block per grid column (``gs_sweep.column_major``)."""
+    return Block(name, (1, Dp, 1), (L, Dp, 1), (L - 1, 0, 0))
+
+
 # ---------------------------------------------------------------------------
 # gs_sweep — fused dense column-serial Gauss-Seidel sweep
 # ---------------------------------------------------------------------------
@@ -74,7 +80,7 @@ def _gs_sweep_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
             Scalar("wb", (1,), dtype="float32"),
         ),
         inputs=(
-            Block("counts", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("counts", Dp, L),
             Block("mu_in", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("theta_in", (Dp, Kp), (Dp, Kp), (0, 0), **carried_in),
             Block("phi_in", (W, Kp), (W, Kp), (0, 0), **carried_in),
@@ -86,10 +92,11 @@ def _gs_sweep_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
             Block("ptot_out", (1, Kp), (1, Kp), (0, 0), carried=True),
             Block("mu_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("res_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
-            Block("loglik", (1, 1), (L, 1), (L - 1, 0)),
+            _column("loglik", Dp, L),
         ),
         scratch=(
             Block("rows_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
+            Block("delta_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
         ),
         # flat operands: wid(0) wb(1) counts(2) mu(3) theta(4) phi(5) ptot(6)
         aliases={4: 0, 5: 1, 6: 2},
@@ -108,12 +115,12 @@ def _scheduled_sweep_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
         grid=(2 * L,),
         scalars=(
             Scalar("word_ids", (Dp, L)),
-            Scalar("word_topics", (W, A)),
+            Scalar("word_topics", (W * A,)),
             Scalar("wb", (1,), dtype="float32"),
         ),
         inputs=(
-            Block("counts", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("token_active", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("counts", Dp, L),
+            _column("token_active", Dp, L),
             Block("mu_in", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("theta_in", (Dp, Kp), (Dp, Kp), (0, 0), carried=True),
             Block("phi_in", (W, Kp), (W, Kp), (0, 0), carried=True),
@@ -125,11 +132,12 @@ def _scheduled_sweep_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
             Block("ptot_out", (1, Kp), (1, Kp), (0, 0), carried=True),
             Block("mu_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("res_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
-            Block("loglik", (1, 1), (L, 1), (L - 1, 0)),
+            _column("loglik", Dp, L),
         ),
         scratch=(
             Block("rows_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
             Block("mask_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
+            Block("delta_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
         ),
         # flat: wid(0) wtop(1) wb(2) counts(3) act(4) mu(5) theta(6) phi(7)
         #       ptot(8)
@@ -149,20 +157,20 @@ def _sharded_probe_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
         grid=(L,),
         scalars=(
             Scalar("word_ids", (Dp, L)),
-            Scalar("word_topics", (W, A)),
+            Scalar("word_topics", (W * A,)),
             Scalar("wb", (1,), dtype="float32"),
         ),
         inputs=(
-            Block("counts", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("token_active", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("counts", Dp, L),
+            _column("token_active", Dp, L),
             Block("mu_in", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("theta_in", (Dp, Kp), (Dp, Kp), (0, 0), carried=True),
             Block("phi_in", (W, Kp), (W, Kp), (0, 0), carried=True),
             Block("ptot_in", (1, Kp), (1, Kp), (0, 0), carried=True),
         ),
         outputs=(
-            Block("s_out", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("pm_out", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("s_out", Dp, L),
+            _column("pm_out", Dp, L),
         ),
         scratch=(
             Block("rows_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
@@ -180,14 +188,14 @@ def _sharded_fold_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
         grid=(2 * L,),                      # emit_loglik high-water mark
         scalars=(
             Scalar("word_ids", (Dp, L)),
-            Scalar("word_topics", (W, A)),
+            Scalar("word_topics", (W * A,)),
             Scalar("wb", (1,), dtype="float32"),
         ),
         inputs=(
-            Block("counts", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("token_active", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("remainder", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("prev_mass", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("counts", Dp, L),
+            _column("token_active", Dp, L),
+            _column("remainder", Dp, L),
+            _column("prev_mass", Dp, L),
             Block("mu_in", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("theta_in", (Dp, Kp), (Dp, Kp), (0, 0), carried=True),
             Block("phi_in", (W, Kp), (W, Kp), (0, 0), carried=True),
@@ -199,11 +207,12 @@ def _sharded_fold_spec(cell: Cell, lane_align: int = LANE) -> LaunchSpec:
             Block("ptot_out", (1, Kp), (1, Kp), (0, 0), carried=True),
             Block("mu_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
             Block("res_out", (1, Dp, Kp), (L, Dp, Kp), (L - 1, 0, 0)),
-            Block("live_mass", (Dp, 1), (Dp, L), (0, L - 1)),
-            Block("loglik_u", (Dp, 1), (Dp, L), (0, L - 1)),
+            _column("live_mass", Dp, L),
+            _column("loglik_u", Dp, L),
         ),
         scratch=(
             Block("rows_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
+            Block("delta_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
             Block("mask_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),
         ),
         # flat: wid(0) wtop(1) wb(2) counts(3) act(4) rem(5) pm(6) mu(7)
@@ -251,7 +260,7 @@ def _theta_sweep_spec_for(phi_dtype: str):
             else cell.W_s
         scalars = [
             Scalar("word_ids", (Dp, L)),
-            Scalar("word_topics", (W, A)),
+            Scalar("word_topics", (W * A,)),
         ]
         if phi_dtype == "int8":
             scalars.append(Scalar("phi_scale", (W,), dtype="float32"))
@@ -264,16 +273,16 @@ def _theta_sweep_spec_for(phi_dtype: str):
             grid=((THETA_CHUNK_SWEEPS + 1) * L,),  # sweeps + eq. 21 columns
             scalars=tuple(scalars),
             inputs=(
-                Block("est_counts", (Dp, 1), (Dp, L), (0, L - 1)),
-                Block("ev_counts", (Dp, 1), (Dp, L), (0, L - 1)),
+                _column("est_counts", Dp, L),
+                _column("ev_counts", Dp, L),
                 Block("theta_in", (Dp, Kp), (Dp, Kp), (0, 0), carried=True),
                 Block("phi_norm", (W, Kp), (W, Kp), (0, 0), carried=True,
                       dtype=phi_dtype, dtype_bytes=phi_bytes),
             ),
             outputs=(
                 Block("theta_out", (Dp, Kp), (Dp, Kp), (0, 0), carried=True),
-                Block("est_ll", (1, Dp, 1), (L, Dp, 1), (L - 1, 0, 0)),
-                Block("ev_ll", (1, Dp, 1), (L, Dp, 1), (L - 1, 0, 0)),
+                _column("est_ll", Dp, L),
+                _column("ev_ll", Dp, L),
             ),
             scratch=(
                 Block("rows_scratch", (Dp, Kp), (Dp, Kp), (0, 0)),

@@ -64,6 +64,7 @@ def scheduled_iem_sweep(
     vocab_size: Optional[int] = None,
     compute_loglik: bool = False,
     plan: Optional[SweepPlan] = None,
+    num_words: Optional[jax.Array] = None,
 ) -> Tuple[LocalState, jax.Array, jax.Array, SchedulerState,
            Optional[jax.Array]]:
     """One dynamic-scheduling sweep: update only active (word, topic) entries.
@@ -83,6 +84,9 @@ def scheduled_iem_sweep(
     *local* residual slice — top-(A/mp) local ids, whose union across
     shards is the balanced size-A active set — and the sweep always takes
     the unified dispatch (the legacy blocked scan has no sharded form).
+
+    ``num_words`` is the real W_s when ``phi_wk``'s trailing rows pad a
+    jit-shape bucket (the λ_w word ranking must not count them).
 
     Returns ``(local, phi, ptot, scheduler, loglik-or-None)``.
     """
@@ -118,7 +122,7 @@ def scheduled_iem_sweep(
     else:
         r_w = scheduler.r_w
         word_thresh = sched_lib.select_active_words_threshold(
-            scheduler, cfg.active_words_frac
+            scheduler, cfg.active_words_frac, num_words
         )
     token_active = (
         jnp.take(r_w, batch.word_ids, axis=0) >= word_thresh
@@ -245,6 +249,7 @@ def foem_minibatch(
     cfg: LDAConfig,
     *,
     vocab_size: Optional[int] = None,
+    num_words: Optional[jax.Array] = None,
 ) -> FOEMMinibatchResult:
     """Run FOEM's inner loop on one minibatch (paper Fig. 4 lines 2-18).
 
@@ -260,6 +265,10 @@ def foem_minibatch(
     the while-loop needs no standalone (D, L, K) perplexity pass.  Coarse
     block counts or ``sweep_impl == "scan"`` keep the legacy blocked scans
     and the separate ``em.training_perplexity`` check.
+
+    ``num_words`` is the real W_s when ``phi_wk_in`` is padded with zero
+    rows to a jit-shape bucket (``FOEMTrainer``); it keeps the λ_w word
+    ranking over the real words only.
     """
     D, L = batch.word_ids.shape
     K = cfg.K
@@ -315,7 +324,7 @@ def foem_minibatch(
         if use_sched:
             return scheduled_iem_sweep(
                 batch, local, phi, ptot, scheduler, cfg, vocab_size=W,
-                compute_loglik=compute_loglik,
+                compute_loglik=compute_loglik, num_words=num_words,
             )
         if use_fused:
             # working-copy form: skip the delta round trip entirely

@@ -64,19 +64,27 @@ def select_active_topics(
 
 
 def select_active_words_threshold(
-    sched: SchedulerState, frac: float
+    sched: SchedulerState, frac: float,
+    num_words: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Residual threshold t such that ~frac·W_s words satisfy r_w >= t.
 
     Returned as a scalar; tokens are masked by ``r_w[word_id] >= t``.  With
-    frac == 1.0 the threshold is -inf (all words active).
+    frac == 1.0 the threshold is -inf (all words active).  ``num_words``
+    (traced) is the real W_s when the rows past it are zero padding of a
+    jit-shape bucket: residuals are non-negative and padding rows stay 0,
+    so the k-th largest of the padded vector is the k-th largest of the
+    real words.
     """
     if frac >= 1.0:
         return jnp.array(-jnp.inf, sched.r_w.dtype)
-    n = sched.r_w.shape[0]
-    k = max(1, int(round(frac * n)))
-    vals, _ = jax.lax.top_k(sched.r_w, k)
-    return vals[-1]
+    if num_words is None:
+        n = sched.r_w.shape[0]
+        k = max(1, int(round(frac * n)))
+        vals, _ = jax.lax.top_k(sched.r_w, k)
+        return vals[-1]
+    k = jnp.maximum(1, jnp.round(frac * num_words).astype(jnp.int32))
+    return jnp.sort(sched.r_w)[::-1][k - 1]
 
 
 def sparse_estep_renorm(

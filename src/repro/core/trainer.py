@@ -32,6 +32,7 @@ from repro.core import em, foem, sem
 from repro.core.streaming import ParameterStore, StreamPrefetcher
 from repro.core.types import GlobalStats, LDAConfig, MinibatchData
 from repro.runtime import faults as fault_lib
+from repro.sparse.docword import pad_vocab_rows
 from repro.sparse.minibatch import Minibatch, MinibatchStream
 
 
@@ -91,7 +92,10 @@ class FOEMTrainer:
         # (read under the store lock — a concurrent stats_window(reset) from
         # the serving side must not observe a torn triple)
         self._stats_base = store.bump_pipeline_stats()
-        # jit cache keyed by (D_s, L, W_s-padded) static shapes
+        # jit cache keyed by the (D_s, L) batch shape and the W_s bucket:
+        # rows are zero-padded to ``docword.VOCAB_BUCKET`` (a multiple of
+        # the sublane tile, so the sweep kernels are eligible) and a
+        # stream of varying W_s compiles once per bucket
         self._jit_cache: Dict = {}
 
     # ------------------------------------------------------------------
@@ -101,9 +105,10 @@ class FOEMTrainer:
             cfg = self.cfg
 
         if algorithm == "foem":
-            def run(key, batch, phi_rows, phi_k, live_w):
+            def run(key, batch, phi_rows, phi_k, live_w, num_words):
                 res = foem.foem_minibatch(
-                    key, batch, phi_rows, phi_k, cfg, vocab_size=live_w
+                    key, batch, phi_rows, phi_k, cfg, vocab_size=live_w,
+                    num_words=num_words,
                 )
                 return (
                     res.phi_wk,
@@ -113,7 +118,7 @@ class FOEMTrainer:
                     res.diag.residual_mass,
                 )
         elif algorithm == "sem":
-            def run(key, batch, phi_rows, phi_k, live_w):
+            def run(key, batch, phi_rows, phi_k, live_w, num_words):
                 stats = GlobalStats(phi_wk=phi_rows, phi_k=phi_k, step=jnp.int32(0))
                 new_stats, local, diag = sem.sem_step(
                     key, batch, stats, cfg, vocab_size=live_w
@@ -217,12 +222,15 @@ class FOEMTrainer:
             self.shift_detector.consume_refresh()
             if self.shift_detector is not None else False
         )
+        num_words = len(phi_rows)
+        padded = pad_vocab_rows(phi_rows)
         step_fn = self._get_step_fn(
-            (batch.word_ids.shape, phi_rows.shape), refresh=refresh
+            (batch.word_ids.shape, padded.shape), refresh=refresh
         )
         live_w = max(self.store.live_vocab, self.cfg.W)
         new_rows, new_phi_k, sweeps, ppl, res_mass = step_fn(
-            sub, batch, jnp.asarray(phi_rows), jnp.asarray(phi_k), live_w
+            sub, batch, jnp.asarray(padded), jnp.asarray(phi_k), live_w,
+            jnp.int32(num_words),
         )
         # One transfer for rows, totals AND the diagnostic scalars: fetching
         # int(sweeps)/float(ppl) separately would stall the prefetch pipeline
@@ -230,6 +238,7 @@ class FOEMTrainer:
         new_rows, new_phi_k, sweeps, ppl, res_mass = jax.device_get(
             (new_rows, new_phi_k, sweeps, ppl, res_mass)
         )
+        new_rows = new_rows[:num_words]         # drop the bucket padding
         new_phi_k = np.asarray(new_phi_k, np.float64)  # lint: host-f64 — RAM accumulator
 
         # post-fold: the local fold is complete but unpublished — a "kill"

@@ -71,12 +71,14 @@ def fits_vmem(num_rows: int, num_docs: int, num_topics: int,
 
 def loglik_partial(cnt, theta, ptot, rows, wb, *, alpha_m1: float,
                    beta_m1: float, k_actual: int):
-    """One column's eq. 3 data-loglik partial against the carried stats.
+    """One column's eq. 3 data-loglik partials against the carried stats.
 
     The stop-rule arithmetic shared by the dense and scheduled sweep
     kernels' loglik phases: eq. 9/10 normalisation, padded topic lanes
     masked out, padded documents inert via their zero counts.  Mirrors
     ``em.map_log_likelihood`` / ``training_perplexity`` term for term.
+    Returns the per-document ``(D, 1)`` column ``x·log lik``; the wrapper
+    sums the emitted (L, D, 1) partials.
     """
     D, K = theta.shape
     th_den = theta.sum(-1, keepdims=True) + k_actual * alpha_m1
@@ -87,17 +89,32 @@ def loglik_partial(cnt, theta, ptot, rows, wb, *, alpha_m1: float,
         lane = jax.lax.broadcasted_iota(jnp.int32, (D, K), 1)
         prod = jnp.where(lane < k_actual, prod, 0.0)
     lik = jnp.maximum(prod.sum(-1, keepdims=True), 1e-30)
-    return (cnt * jnp.log(lik)).sum()
+    return cnt * jnp.log(lik)
+
+
+def scatter_rows(wid_ref, col, phi_ref, delta_ref, num_docs: int):
+    """Fold the staged (D, K) Δ rows into φ̂ at the column's word rows.
+
+    Δ goes through a VMEM ref so each document's row is a dynamic
+    ``pl.ds`` load — the TPU compiler has no value-level dynamic slice.
+    """
+    def go(d, _):
+        w = wid_ref[d, col]
+        phi_ref[pl.ds(w, 1), :] = (
+            phi_ref[pl.ds(w, 1), :] + delta_ref[pl.ds(d, 1), :]
+        )
+        return 0
+    jax.lax.fori_loop(0, num_docs, go, 0)
 
 
 def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                     num_cols: int, emit_loglik: bool, double_buffer: bool):
     """Build the kernel body for a static (loglik, buffering) configuration.
 
-    Ref order: scalar prefetch (wid, wb), inputs (counts, μ column, θ̂, φ̂,
-    φ̂(k)), outputs (θ̂, φ̂, φ̂(k) carried; μ, residual columns; loglik
-    partials when emitted), scratch (rows buffer; DMA semaphore when
-    double-buffered).
+    Ref order: scalar prefetch (wid, wb), inputs (counts column, μ column,
+    θ̂, φ̂, φ̂(k)), outputs (θ̂, φ̂, φ̂(k) carried; μ, residual columns;
+    loglik partial columns when emitted), scratch (rows buffer, Δ buffer;
+    DMA semaphore when double-buffered).
     """
 
     def kernel(wid_ref, wb_ref, counts_ref, mu_in_ref, theta_in_ref,
@@ -106,8 +123,8 @@ def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
         theta_ref, phi_ref, ptot_ref, mu_ref, res_ref = rest[:5]
         ll_ref = rest[5] if emit_loglik else None
         scratch = rest[n_out:]
-        rows_ref = scratch[0]
-        sem = scratch[1] if double_buffer else None
+        rows_ref, delta_ref = scratch[:2]
+        sem = scratch[2] if double_buffer else None
 
         l = pl.program_id(0)
         D, K = theta_ref.shape
@@ -149,7 +166,7 @@ def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                 prefetch(0, start=True)
 
         def sweep_col():
-            cnt = counts_ref[...]                   # (D, 1)
+            cnt = counts_ref[0]                     # (D, 1)
             mu_old = mu_in_ref[0]                   # (D, K)
             theta = theta_ref[...]
             ptot = ptot_ref[...]                    # (1, K)
@@ -180,13 +197,8 @@ def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             # ---- Gauss-Seidel fold: θ̂/φ̂/φ̂(k) updated before next col ----
             theta_ref[...] = theta + delta
             ptot_ref[...] = ptot + delta.sum(0, keepdims=True)
-
-            def scatter(d, _):
-                w = wid_ref[d, l]
-                row = jax.lax.dynamic_slice(delta, (d, 0), (1, K))
-                phi_ref[pl.ds(w, 1), :] = phi_ref[pl.ds(w, 1), :] + row
-                return 0
-            jax.lax.fori_loop(0, D, scatter, 0)
+            delta_ref[...] = delta
+            scatter_rows(wid_ref, l, phi_ref, delta_ref, D)
 
             if double_buffer:
                 # earliest consistent point: the scatter above is what the
@@ -197,15 +209,13 @@ def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
 
             mu_ref[0] = mu_new
             res_ref[0] = cnt * jnp.abs(mu_new - mu_old)
-            if emit_loglik:
-                ll_ref[0, 0] = 0.0          # overwritten by the ppl phase
 
         def ppl_col():
             # Stop-rule phase: per-column eq. 3 data-loglik partials against
             # the FINAL carried stats (phase runs after the last fold).
             gather_sync(l - num_cols)
-            ll_ref[0, 0] = loglik_partial(
-                counts_ref[...], theta_ref[...], ptot_ref[...], rows_ref[...],
+            ll_ref[0] = loglik_partial(
+                counts_ref[0], theta_ref[...], ptot_ref[...], rows_ref[...],
                 wb, alpha_m1=alpha_m1, beta_m1=beta_m1, k_actual=k_actual,
             )
 
@@ -221,6 +231,50 @@ def _make_gs_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             sweep_col()
 
     return kernel
+
+
+def compiler_params():
+    """Mosaic parameters shared by the column-serial launches: the column
+    grid is sequential (Gauss-Seidel order, VMEM-carried statistics)."""
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def column_index_maps(num_cols: int, emit_loglik: bool):
+    """Block-index maps over a sweep grid of ``num_cols`` (+ ``num_cols``
+    stop-rule steps when ``emit_loglik``).
+
+    Returns ``(col_of, pin_of, ll_of)``: ``col_of`` re-walks the per-column
+    inputs in the stop-rule phase, ``pin_of`` keeps the μ/residual output
+    blocks on the last column there (no re-flush of written output), and
+    ``ll_of`` holds the loglik output on block 0 through the sweep phase,
+    which never writes it, so each loglik block is flushed once, after the
+    stop-rule phase has written it.
+    """
+    L = num_cols
+    if not emit_loglik:
+        ident = lambda l: l
+        return ident, ident, ident
+    return (
+        lambda l: jax.lax.rem(l, L),
+        lambda l: jnp.minimum(l, L - 1),
+        lambda l: jnp.maximum(l - L, 0),
+    )
+
+
+def column_major(x: jax.Array) -> jax.Array:
+    """(D, L) per-token values -> (L, D, 1): one (1, D, 1) block per column.
+
+    A per-column block of a (D, L) array would be (D, 1), which breaks the
+    TPU tiling rule (the minor block dim must be a multiple of 128 or the
+    whole array dim); stacked by column the block's last two dims are the
+    array's own.
+    """
+    return x.T[:, :, None]
+
+
+def from_column_major(x: jax.Array) -> jax.Array:
+    """Inverse of :func:`column_major`: (L, D, 1) -> (D, L)."""
+    return x[..., 0].T
 
 
 @functools.partial(
@@ -280,21 +334,14 @@ def gs_sweep_pallas(
         emit_loglik=emit_loglik, double_buffer=double_buffer,
     )
     wb_arr = jnp.reshape(jnp.asarray(wb, mu.dtype), (1,))
-
-    # The stop-rule phase revisits the columns with the carried stats final:
-    # per-column operands re-walk via l % L while the μ/residual blocks stay
-    # pinned on the last column (no re-flush of already-written output).
+    col_of, pin_of, ll_of = column_index_maps(L, emit_loglik)
     grid_len = 2 * L if emit_loglik else L
-
-    def col_of(l):
-        return jax.lax.rem(l, L) if emit_loglik else l
-
-    def pin_of(l):
-        return jnp.minimum(l, L - 1) if emit_loglik else l
 
     out_specs = [
         pl.BlockSpec((Dp, Kp), lambda l, wid, wb: (0, 0)),
-        pl.BlockSpec((Wrows, Kp), lambda l, wid, wb: (0, 0)),
+        # named VMEM: the double-buffered gather DMAs from this block
+        pl.BlockSpec((Wrows, Kp), lambda l, wid, wb: (0, 0),
+                     memory_space=pltpu.VMEM),
         pl.BlockSpec((1, Kp), lambda l, wid, wb: (0, 0)),
         pl.BlockSpec((1, Dp, Kp), lambda l, wid, wb: (pin_of(l), 0, 0)),
         pl.BlockSpec((1, Dp, Kp), lambda l, wid, wb: (pin_of(l), 0, 0)),
@@ -307,10 +354,15 @@ def gs_sweep_pallas(
         jax.ShapeDtypeStruct((L, Dp, Kp), mu.dtype),
     ]
     if emit_loglik:
-        out_specs.append(pl.BlockSpec((1, 1), lambda l, wid, wb: (col_of(l), 0)))
-        out_shape.append(jax.ShapeDtypeStruct((L, 1), mu.dtype))
+        out_specs.append(
+            pl.BlockSpec((1, Dp, 1), lambda l, wid, wb: (ll_of(l), 0, 0))
+        )
+        out_shape.append(jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype))
 
-    scratch_shapes = [pltpu.VMEM((Dp, Kp), mu.dtype)]
+    scratch_shapes = [
+        pltpu.VMEM((Dp, Kp), mu.dtype),        # gathered φ̂ rows
+        pltpu.VMEM((Dp, Kp), mu.dtype),        # staged Δ rows
+    ]
     if double_buffer:
         scratch_shapes.append(pltpu.SemaphoreType.DMA)
 
@@ -318,7 +370,7 @@ def gs_sweep_pallas(
         num_scalar_prefetch=2,
         grid=(grid_len,),
         in_specs=[
-            pl.BlockSpec((Dp, 1), lambda l, wid, wb: (0, col_of(l))),
+            pl.BlockSpec((1, Dp, 1), lambda l, wid, wb: (col_of(l), 0, 0)),
             pl.BlockSpec((1, Dp, Kp), lambda l, wid, wb: (pin_of(l), 0, 0)),
             pl.BlockSpec((Dp, Kp), lambda l, wid, wb: (0, 0)),
             pl.BlockSpec((Wrows, Kp), lambda l, wid, wb: (0, 0)),
@@ -333,11 +385,10 @@ def gs_sweep_pallas(
         out_shape=out_shape,
         # flat operands: wid(0) wb(1) counts(2) mu(3) theta(4) phi(5) ptot(6)
         input_output_aliases={4: 0, 5: 1, 6: 2},
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(word_ids, wb_arr, counts, mu_cols, theta, phi_wk, phi_k[None, :])
+    )(word_ids, wb_arr, column_major(counts), mu_cols, theta, phi_wk,
+      phi_k[None, :])
 
     theta_out, phi_out, ptot_out, mu_out, res_out = outs[:5]
     loglik = outs[5].sum() if emit_loglik else None

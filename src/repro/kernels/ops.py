@@ -25,8 +25,12 @@ serving/evaluation consumer (``perplexity.fit_theta_fixed_phi`` /
 """
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
-from typing import Callable, Optional, Tuple
+import itertools
+import threading
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +60,64 @@ from repro.kernels.topk_estep import topk_estep_pallas
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+@dataclasses.dataclass(frozen=True)
+class Dispatch:
+    """One trace-time dispatch decision of ``sweep`` or ``infer``.
+
+    ``path`` is what ran: ``"pallas"`` (compiled kernel), ``"interpret"``
+    (kernel body on the CPU) or ``"portable"`` (the jnp mirror);
+    ``reason`` says why — ``"auto"`` when the kernel was eligible,
+    ``"VMEM"`` / ``"sublane"`` / ``"no TPU"`` / ``"psum hooks"`` when the
+    auto path fell back, ``"forced"`` or ``"plan"`` when the caller chose.
+    ``shape`` is ``(D, L, K, W_s)``.  Decisions are made while tracing, so
+    a jit cache hit records nothing.
+    """
+
+    seq: int
+    entry: str
+    path: str
+    reason: str
+    shape: Tuple[int, int, int, int]
+
+    def __str__(self) -> str:
+        return f"{self.entry} {self.path}: {self.reason}"
+
+
+_DISPATCH_LOG: collections.deque = collections.deque(maxlen=1024)
+_DISPATCH_SEQ = itertools.count()
+_DISPATCH_LOCK = threading.Lock()
+
+
+def _record_dispatch(entry, path, reason, shape) -> None:
+    with _DISPATCH_LOCK:
+        _DISPATCH_LOG.append(
+            Dispatch(next(_DISPATCH_SEQ), entry, path, reason,
+                     tuple(int(x) for x in shape))
+        )
+
+
+def dispatch_log(since: int = -1) -> List[Dispatch]:
+    """The recorded dispatch decisions with ``seq > since`` (the newest
+    1024), oldest first — how a caller sees whether a kernel or the
+    portable path ran, and why."""
+    with _DISPATCH_LOCK:
+        return [d for d in _DISPATCH_LOG if d.seq > since]
+
+
+def _auto_path(*, fits: bool, rows: int, sublane: int,
+               hooked: bool = False) -> Tuple[bool, str]:
+    """The auto rule shared by ``sweep`` and ``infer``: (use kernel, reason)."""
+    if hooked:
+        return False, "psum hooks"
+    if not on_tpu():
+        return False, "no TPU"
+    if not fits:
+        return False, "VMEM"
+    if rows % sublane:
+        return False, "sublane"
+    return True, "auto"
 
 
 # ---------------------------------------------------------------------------
@@ -550,7 +612,8 @@ def sweep(
       delta-compacted portable scan (whose dense E-step still routes
       through the fused kernel on TPU).  ``interpret=True`` forces the
       kernel body on CPU (tests); ``use_pallas=False`` forces the pure-jnp
-      oracle.
+      oracle.  Each choice is recorded with its reason in
+      :func:`dispatch_log`.
     * ``norm_psum`` / ``renorm_psum`` are the raw reduction hooks the
       sharded plan's legacy mode is built on, kept public for tests and
       custom meshes: ``norm_psum`` reduces the dense E-step normaliser
@@ -629,16 +692,19 @@ def _sweep_impl(
             raise ValueError(
                 "pass EITHER a sharded SweepPlan OR raw psum hooks, not both"
             )
-        how = plan.impl
+        how, reason = plan.impl, "plan"
         if how == "auto":
             # hooks mode is portable-only, so auto resolves to a kernel
             # path only for the two-phase engine
-            fits = sharded_fits_vmem(phi_wk.shape[0], D, K, scheduled)
-            how = "pallas" if (
-                plan.two_phase and on_tpu() and fits
-                and phi_wk.shape[0] % SUBLANE == 0
-            ) else "portable"
+            kernel, reason = _auto_path(
+                fits=sharded_fits_vmem(phi_wk.shape[0], D, K, scheduled),
+                rows=phi_wk.shape[0], sublane=SUBLANE,
+                hooked=not plan.two_phase,
+            )
+            how = "pallas" if kernel else "portable"
         if plan.two_phase:
+            _record_dispatch("sweep", how, f"two-phase {reason}",
+                             (D, L, K, phi_wk.shape[0]))
             return _sweep_two_phase(
                 word_ids, counts, mu, theta, phi_wk, phi_k,
                 word_topics, token_active,
@@ -652,11 +718,11 @@ def _sweep_impl(
                 "portable path; a collective cannot cross a kernel boundary"
             )
         hook = lambda x: lax.psum(x, plan.axis_name)
-        r = sweep(
+        r = _sweep_impl(
             word_ids, counts, mu, theta, phi_wk, phi_k,
             alpha_m1=alpha_m1, beta_m1=beta_m1, wb=wb,
             word_topics=word_topics, token_active=token_active,
-            unroll=unroll, use_pallas=False,
+            unroll=unroll,
             norm_psum=None if scheduled else hook,
             renorm_psum=hook if scheduled else None,
         )
@@ -672,7 +738,9 @@ def _sweep_impl(
                 loglik=_assemble_sharded_loglik(counts, u_glob, th_den)
             )
         return r
-    if plan is not None:
+    reason = "forced"
+    if plan is not None and plan.impl != "auto":
+        reason = "plan"
         if plan.impl == "pallas":
             use_pallas = True
         elif plan.impl == "interpret":
@@ -686,15 +754,21 @@ def _sweep_impl(
     if use_pallas is False:
         interpret = False       # explicit False wins: pure-jnp oracle
     elif auto:
-        fits = (sched_fits_vmem if scheduled else fits_vmem)(
-            phi_wk.shape[0], D, K
-        )
         # a ragged W_s violates the compiled kernels' sublane layout
         # (ContractError when forced); auto simply stays portable
-        use_pallas = (
-            on_tpu() and fits and not hooked
-            and phi_wk.shape[0] % SUBLANE == 0
+        use_pallas, auto_reason = _auto_path(
+            fits=(sched_fits_vmem if scheduled else fits_vmem)(
+                phi_wk.shape[0], D, K
+            ),
+            rows=phi_wk.shape[0], sublane=SUBLANE, hooked=hooked,
         )
+        if not interpret:
+            reason = auto_reason
+    _record_dispatch(
+        "sweep",
+        "interpret" if interpret else ("pallas" if use_pallas else "portable"),
+        reason, (D, L, K, phi_wk.shape[0]),
+    )
     if hooked and (use_pallas or interpret):
         # refuse rather than silently downgrade: a collective cannot cross
         # a kernel boundary, and a parity test passing a hook would
@@ -841,7 +915,8 @@ def infer(
     * Dispatch: the single-launch Pallas kernel per chunk on TPU whenever
       the (W_s + D, K) working set fits VMEM; the pure-jnp mirror
       elsewhere.  ``interpret=True`` forces the kernel body on CPU
-      (tests); ``use_pallas=False`` forces the oracle.
+      (tests); ``use_pallas=False`` forces the oracle.  Each choice is
+      recorded with its reason in :func:`dispatch_log`.
     * ``plan`` (``core.types.SweepPlan``) with ``axis_name`` set runs the
       fixed point *inside* ``shard_map`` with the topic axis sharded over
       that mesh axis: the per-token normalisers, the θ̂ normaliser and the
@@ -887,6 +962,7 @@ def infer(
     ev = jnp.zeros_like(est_counts) if ev_counts is None else ev_counts
 
     axis_name = None
+    reason = "forced"
     if plan is not None and plan.axis_name is not None:
         if plan.impl in ("pallas", "interpret"):
             raise ValueError(
@@ -895,9 +971,10 @@ def infer(
             )
         axis_name = plan.axis_name
         k_alpha = (K * lax.psum(1, axis_name)) * alpha_m1   # global K·(α−1)
-        use_pallas, interpret = False, False
+        use_pallas, interpret, reason = False, False, "sharded plan"
     else:
-        if plan is not None:
+        if plan is not None and plan.impl != "auto":
+            reason = "plan"
             if plan.impl == "pallas":
                 use_pallas = True
             elif plan.impl == "interpret":
@@ -908,12 +985,18 @@ def infer(
         if use_pallas is False:
             interpret = False           # explicit False wins: pure-jnp oracle
         elif use_pallas is None:
-            use_pallas = (
-                on_tpu()
-                and theta_fits_vmem(phi_norm.shape[0], D, K,
-                                    phi_dtype=phi_dtype)
-                and phi_norm.shape[0] % PHI_SUBLANE[phi_dtype] == 0
+            use_pallas, auto_reason = _auto_path(
+                fits=theta_fits_vmem(phi_norm.shape[0], D, K,
+                                     phi_dtype=phi_dtype),
+                rows=phi_norm.shape[0], sublane=PHI_SUBLANE[phi_dtype],
             )
+            if not interpret:
+                reason = auto_reason
+    _record_dispatch(
+        "infer",
+        "interpret" if interpret else ("pallas" if use_pallas else "portable"),
+        reason, (D, L, K, phi_norm.shape[0]),
+    )
 
     # Quantize the frozen φ block ONCE, outside the while_loop: both paths
     # then read the same stored values, so kernel/portable parity holds

@@ -49,7 +49,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.budget import DEFAULT_VMEM_BUDGET
 from repro.analysis.checks import kernel_fits_vmem
-from repro.kernels.gs_sweep import loglik_partial
+from repro.kernels.gs_sweep import (
+    column_index_maps,
+    column_major,
+    compiler_params,
+    loglik_partial,
+    scatter_rows,
+)
 
 
 def sched_fits_vmem(num_rows: int, num_docs: int, num_topics: int,
@@ -63,21 +69,35 @@ def sched_fits_vmem(num_rows: int, num_docs: int, num_topics: int,
                             num_topics, budget)
 
 
+def expand_lane_mask(wtop_ref, w, active_topics: int, lane, dtype):
+    """One word's (1, K) {0, 1} lane mask from its active-topic ids.
+
+    ``wtop_ref`` is the flattened (W_s·A,) scalar-prefetched table: a 2-D
+    (W_s, A) SMEM operand pads its minor dim to 128 words, which at
+    W_s = 8k is 4 MiB — four times the scalar memory.
+    """
+    base = w * active_topics
+    m = jnp.zeros(lane.shape, dtype)
+    for a in range(active_topics):              # static unroll, A ≈ 16
+        m = jnp.maximum(m, (lane == wtop_ref[base + a]).astype(dtype))
+    return m
+
+
 def _make_sched_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                        num_cols: int, active_topics: int, emit_loglik: bool):
     """Kernel body for a static (A, loglik) configuration.
 
-    Ref order: scalar prefetch (wid, word-topics, wb), inputs (counts,
-    active-word column, μ column, θ̂, φ̂, φ̂(k)), outputs (θ̂, φ̂, φ̂(k)
-    carried; μ, residual columns; loglik partials when emitted), scratch
-    (gathered rows, lane mask).
+    Ref order: scalar prefetch (wid, flat word-topics, wb), inputs (counts
+    column, active-word column, μ column, θ̂, φ̂, φ̂(k)), outputs (θ̂, φ̂,
+    φ̂(k) carried; μ, residual columns; loglik partial columns when
+    emitted), scratch (gathered rows, lane mask, staged Δ).
     """
 
     def kernel(wid_ref, wtop_ref, wb_ref, counts_ref, act_ref, mu_in_ref,
                theta_in_ref, phi_in_ref, ptot_in_ref, *rest):
         theta_ref, phi_ref, ptot_ref, mu_ref, res_ref = rest[:5]
         ll_ref = rest[5] if emit_loglik else None
-        rows_ref, mask_ref = rest[6:] if emit_loglik else rest[5:]
+        rows_ref, mask_ref, delta_ref = rest[6:] if emit_loglik else rest[5:]
 
         l = pl.program_id(0)
         D, K = theta_ref.shape
@@ -90,24 +110,21 @@ def _make_sched_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             ptot_ref[...] = ptot_in_ref[...]
 
         def sweep_col():
-            cnt = counts_ref[...]                   # (D, 1)
-            act = act_ref[...]                      # (D, 1) ∈ {0, 1}
+            cnt = counts_ref[0]                     # (D, 1)
+            act = act_ref[0]                        # (D, 1) ∈ {0, 1}
             mu_old = mu_in_ref[0]                   # (D, K)
             theta = theta_ref[...]
             ptot = ptot_ref[...]                    # (1, K)
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
             # ---- serial gather: the word's φ̂ row AND its active-topic
-            # lane mask, expanded from the prefetched (W_s, A) ids ----
+            # lane mask, expanded from the prefetched word-topic table ----
             def gather(d, _):
                 w = wid_ref[d, l]
                 rows_ref[pl.ds(d, 1), :] = phi_ref[pl.ds(w, 1), :]
-                m = jnp.zeros((1, K), mu_old.dtype)
-                for a in range(active_topics):      # static unroll, A ≈ 16
-                    m = jnp.maximum(
-                        m, (lane == wtop_ref[w, a]).astype(mu_old.dtype)
-                    )
-                mask_ref[pl.ds(d, 1), :] = m
+                mask_ref[pl.ds(d, 1), :] = expand_lane_mask(
+                    wtop_ref, w, active_topics, lane, mu_old.dtype
+                )
                 return 0
             jax.lax.fori_loop(0, D, gather, 0)
 
@@ -130,18 +147,11 @@ def _make_sched_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             # ---- Gauss-Seidel fold before the next column ----
             theta_ref[...] = theta + delta
             ptot_ref[...] = ptot + delta.sum(0, keepdims=True)
-
-            def scatter(d, _):
-                w = wid_ref[d, l]
-                row = jax.lax.dynamic_slice(delta, (d, 0), (1, K))
-                phi_ref[pl.ds(w, 1), :] = phi_ref[pl.ds(w, 1), :] + row
-                return 0
-            jax.lax.fori_loop(0, D, scatter, 0)
+            delta_ref[...] = delta
+            scatter_rows(wid_ref, l, phi_ref, delta_ref, D)
 
             mu_ref[0] = mu_new
             res_ref[0] = jnp.abs(delta)             # eq. 36 replacement value
-            if emit_loglik:
-                ll_ref[0, 0] = 0.0          # overwritten by the ppl phase
 
         def ppl_col():
             # Stop-rule phase against the FINAL carried stats — shared
@@ -151,8 +161,8 @@ def _make_sched_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                 rows_ref[pl.ds(d, 1), :] = phi_ref[pl.ds(w, 1), :]
                 return 0
             jax.lax.fori_loop(0, D, gather, 0)
-            ll_ref[0, 0] = loglik_partial(
-                counts_ref[...], theta_ref[...], ptot_ref[...], rows_ref[...],
+            ll_ref[0] = loglik_partial(
+                counts_ref[0], theta_ref[...], ptot_ref[...], rows_ref[...],
                 wb, alpha_m1=alpha_m1, beta_m1=beta_m1, k_actual=k_actual,
             )
 
@@ -230,14 +240,8 @@ def scheduled_sweep_pallas(
         active_topics=A, emit_loglik=emit_loglik,
     )
     wb_arr = jnp.reshape(jnp.asarray(wb, mu.dtype), (1,))
-
+    col_of, pin_of, ll_of = column_index_maps(L, emit_loglik)
     grid_len = 2 * L if emit_loglik else L
-
-    def col_of(l):
-        return jax.lax.rem(l, L) if emit_loglik else l
-
-    def pin_of(l):
-        return jnp.minimum(l, L - 1) if emit_loglik else l
 
     out_specs = [
         pl.BlockSpec((Dp, Kp), lambda l, wid, wt, wb: (0, 0)),
@@ -255,16 +259,17 @@ def scheduled_sweep_pallas(
     ]
     if emit_loglik:
         out_specs.append(
-            pl.BlockSpec((1, 1), lambda l, wid, wt, wb: (col_of(l), 0))
+            pl.BlockSpec((1, Dp, 1), lambda l, wid, wt, wb: (ll_of(l), 0, 0))
         )
-        out_shape.append(jax.ShapeDtypeStruct((L, 1), mu.dtype))
+        out_shape.append(jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype))
 
+    col = pl.BlockSpec((1, Dp, 1), lambda l, wid, wt, wb: (col_of(l), 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(grid_len,),
         in_specs=[
-            pl.BlockSpec((Dp, 1), lambda l, wid, wt, wb: (0, col_of(l))),
-            pl.BlockSpec((Dp, 1), lambda l, wid, wt, wb: (0, col_of(l))),
+            col,                                 # counts column
+            col,                                 # active-word column
             pl.BlockSpec((1, Dp, Kp), lambda l, wid, wt, wb: (pin_of(l), 0, 0)),
             pl.BlockSpec((Dp, Kp), lambda l, wid, wt, wb: (0, 0)),
             pl.BlockSpec((Wrows, Kp), lambda l, wid, wt, wb: (0, 0)),
@@ -274,6 +279,7 @@ def scheduled_sweep_pallas(
         scratch_shapes=[
             pltpu.VMEM((Dp, Kp), mu.dtype),      # gathered φ̂ rows
             pltpu.VMEM((Dp, Kp), mu.dtype),      # active-topic lane mask
+            pltpu.VMEM((Dp, Kp), mu.dtype),      # staged Δ rows
         ],
     )
     outs = pl.pallas_call(
@@ -283,12 +289,10 @@ def scheduled_sweep_pallas(
         # flat operands: wid(0) wtop(1) wb(2) counts(3) act(4) mu(5)
         #                theta(6) phi(7) ptot(8)
         input_output_aliases={6: 0, 7: 1, 8: 2},
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(word_ids, word_topics, wb_arr, counts, act, mu_cols, theta, phi_wk,
-      phi_k[None, :])
+    )(word_ids, word_topics.reshape(-1), wb_arr, column_major(counts),
+      column_major(act), mu_cols, theta, phi_wk, phi_k[None, :])
 
     theta_out, phi_out, ptot_out, mu_out, res_out = outs[:5]
     loglik = outs[5].sum() if emit_loglik else None

@@ -67,6 +67,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.budget import DEFAULT_VMEM_BUDGET
 from repro.analysis.checks import kernel_fits_vmem
+from repro.kernels.gs_sweep import (
+    column_index_maps,
+    column_major,
+    compiler_params,
+    from_column_major,
+    scatter_rows,
+)
+from repro.kernels.scheduled_sweep import expand_lane_mask
 
 
 def sharded_fits_vmem(num_rows: int, num_docs: int, num_topics: int,
@@ -87,16 +95,14 @@ def sharded_fits_vmem(num_rows: int, num_docs: int, num_topics: int,
 
 
 def _expand_mask(wid_ref, wtop_ref, mask_ref, l, D, K, active_topics, dtype):
-    """Serial per-document expansion of the prefetched (W_s, A) active-topic
-    ids into the (D, K) lane mask (shared by probe and fold)."""
+    """Serial per-document expansion of the prefetched (flattened) active-
+    topic table into the (D, K) lane mask (shared by probe and fold)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
     def go(d, _):
-        w = wid_ref[d, l]
-        m = jnp.zeros((1, K), dtype)
-        for a in range(active_topics):          # static unroll, A ≈ 16
-            m = jnp.maximum(m, (lane == wtop_ref[w, a]).astype(dtype))
-        mask_ref[pl.ds(d, 1), :] = m
+        mask_ref[pl.ds(d, 1), :] = expand_lane_mask(
+            wtop_ref, wid_ref[d, l], active_topics, lane, dtype
+        )
         return 0
     jax.lax.fori_loop(0, D, go, 0)
 
@@ -133,7 +139,7 @@ def _make_probe_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
         l = pl.program_id(0)
         D, K = theta_ref.shape
         wb = wb_ref[0]
-        cnt = counts_ref[...]                   # (D, 1)
+        cnt = counts_ref[0]                     # (D, 1)
         mu_old = mu_in_ref[0]                   # (D, K)
 
         def gather(d, _):
@@ -145,7 +151,7 @@ def _make_probe_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
         if scheduled:
             _expand_mask(wid_ref, wtop_ref, mask_ref, l, D, K,
                          active_topics, mu_old.dtype)
-            mask = mask_ref[...] * act_ref[...]
+            mask = mask_ref[...] * act_ref[0]
             ex = cnt * mu_old * mask
         else:
             mask = None
@@ -158,9 +164,9 @@ def _make_probe_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
         if scheduled:
             num = num * mask
         num = _lane_guard(num, k_actual)
-        s_ref[...] = num.sum(-1, keepdims=True)
+        s_ref[0] = num.sum(-1, keepdims=True)
         if scheduled:
-            pm_ref[...] = _lane_guard(mu_old * mask, k_actual).sum(
+            pm_ref[0] = _lane_guard(mu_old * mask, k_actual).sum(
                 -1, keepdims=True
             )
 
@@ -220,23 +226,23 @@ def sharded_probe_pallas(
         scheduled=scheduled,
     )
 
-    col = pl.BlockSpec((Dp, 1), lambda l, *p: (0, l))
+    col = pl.BlockSpec((1, Dp, 1), lambda l, *p: (l, 0, 0))
     mu_spec = pl.BlockSpec((1, Dp, Kp), lambda l, *p: (l, 0, 0))
     full = lambda shape: pl.BlockSpec(shape, lambda l, *p: (0,) * len(shape))
 
     in_specs = [col]                            # counts
-    operands = [counts]
+    operands = [column_major(counts)]
     if scheduled:
         in_specs.append(col)                    # active column
-        operands.append(token_active.astype(mu.dtype))
+        operands.append(column_major(token_active.astype(mu.dtype)))
     in_specs += [mu_spec, full((Dp, Kp)), full((Wrows, Kp)), full((1, Kp))]
     operands += [mu_cols, theta, phi_wk, phi_k[None, :]]
 
     out_specs = [col]
-    out_shape = [jax.ShapeDtypeStruct((Dp, L), mu.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype)]
     if scheduled:
         out_specs.append(col)
-        out_shape.append(jax.ShapeDtypeStruct((Dp, L), mu.dtype))
+        out_shape.append(jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype))
 
     scratch_shapes = [pltpu.VMEM((Dp, Kp), mu.dtype)]        # gathered rows
     if scheduled:
@@ -249,20 +255,18 @@ def sharded_probe_pallas(
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    prefetch = (word_ids, word_topics, wb_arr) if scheduled else (
+    prefetch = (word_ids, word_topics.reshape(-1), wb_arr) if scheduled else (
         word_ids, wb_arr
     )
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*prefetch, *operands)
-    s = outs[0][:D]
-    pm = outs[1][:D] if scheduled else None
+    s = from_column_major(outs[0])[:D]
+    pm = from_column_major(outs[1])[:D] if scheduled else None
     return s, pm
 
 
@@ -299,7 +303,7 @@ def _make_fold_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
         ll_ref = None
         if emit_loglik:
             ll_ref = rest[i]; i += 1
-        rows_ref = rest[i]; i += 1
+        rows_ref, delta_ref = rest[i:i + 2]; i += 2
         mask_ref = rest[i] if scheduled else None
 
         l = pl.program_id(0)
@@ -323,15 +327,15 @@ def _make_fold_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                              active_topics, rows_ref.dtype)
 
         def sweep_col():
-            cnt = counts_ref[...]                   # (D, 1)
-            rem = rem_ref[...]                      # (D, 1) other shards' Σnum
+            cnt = counts_ref[0]                     # (D, 1)
+            rem = rem_ref[0]                        # (D, 1) other shards' Σnum
             mu_old = mu_in_ref[0]                   # (D, K)
             theta = theta_ref[...]
             ptot = ptot_ref[...]                    # (1, K)
 
             gather(l, scheduled)
             if scheduled:
-                mask = mask_ref[...] * act_ref[...]
+                mask = mask_ref[...] * act_ref[0]
                 ex = cnt * mu_old * mask
             else:
                 ex = cnt * mu_old
@@ -349,7 +353,7 @@ def _make_fold_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             denom = jnp.maximum(rem + num.sum(-1, keepdims=True), 1e-30)
             if scheduled:
                 # eq. 38 renorm to the GLOBAL previous active mass
-                mu_new = mask * (num / denom * pm_ref[...]) + (
+                mu_new = mask * (num / denom * pm_ref[0]) + (
                     1.0 - mask
                 ) * mu_old
                 delta = cnt * (mu_new - mu_old)     # zero off the active set
@@ -358,25 +362,18 @@ def _make_fold_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
                 mu_new = num / denom
                 delta = cnt * mu_new - ex
                 live = _lane_guard(mu_new, k_actual)
-            m_ref[...] = live.sum(-1, keepdims=True)
+            m_ref[0] = live.sum(-1, keepdims=True)
 
             # ---- Gauss-Seidel fold before the next column ----
             theta_ref[...] = theta + delta
             ptot_ref[...] = ptot + delta.sum(0, keepdims=True)
-
-            def scatter(d, _):
-                w = wid_ref[d, l]
-                row = jax.lax.dynamic_slice(delta, (d, 0), (1, K))
-                phi_ref[pl.ds(w, 1), :] = phi_ref[pl.ds(w, 1), :] + row
-                return 0
-            jax.lax.fori_loop(0, D, scatter, 0)
+            delta_ref[...] = delta
+            scatter_rows(wid_ref, l, phi_ref, delta_ref, D)
 
             mu_ref[0] = mu_new
             res_ref[0] = jnp.abs(delta) if scheduled else (
                 cnt * jnp.abs(mu_new - mu_old)
             )
-            if emit_loglik:
-                ll_ref[...] = jnp.zeros_like(cnt)  # ppl phase overwrites
 
         def ppl_col():
             # Stop-rule phase against the FINAL carried stats.  Unlike the
@@ -388,7 +385,7 @@ def _make_fold_kernel(*, alpha_m1: float, beta_m1: float, k_actual: int,
             ph_n = (rows_ref[...] + beta_m1) / jnp.maximum(
                 ptot_ref[...] + wb, 1e-30
             )
-            ll_ref[...] = _lane_guard(th_n * ph_n, k_actual).sum(
+            ll_ref[0] = _lane_guard(th_n * ph_n, k_actual).sum(
                 -1, keepdims=True
             )
 
@@ -474,28 +471,23 @@ def sharded_fold_pallas(
     )
 
     grid_len = 2 * L if emit_loglik else L
+    col_of, pin_of, ll_of = column_index_maps(L, emit_loglik)
 
-    def col_of(l):
-        return jax.lax.rem(l, L) if emit_loglik else l
-
-    def pin_of(l):
-        return jnp.minimum(l, L - 1) if emit_loglik else l
-
-    col = pl.BlockSpec((Dp, 1), lambda l, *p: (0, col_of(l)))
-    col_pin = pl.BlockSpec((Dp, 1), lambda l, *p: (0, pin_of(l)))
+    col = pl.BlockSpec((1, Dp, 1), lambda l, *p: (col_of(l), 0, 0))
+    col_pin = pl.BlockSpec((1, Dp, 1), lambda l, *p: (pin_of(l), 0, 0))
     mu_spec = pl.BlockSpec((1, Dp, Kp), lambda l, *p: (pin_of(l), 0, 0))
     full = lambda shape: pl.BlockSpec(shape, lambda l, *p: (0,) * len(shape))
 
     in_specs = [col]                            # counts
-    operands = [counts]
+    operands = [column_major(counts)]
     if scheduled:
         in_specs.append(col)                    # active column
-        operands.append(token_active.astype(mu.dtype))
+        operands.append(column_major(token_active.astype(mu.dtype)))
     in_specs.append(col)                        # remainder column
-    operands.append(remainder.astype(mu.dtype))
+    operands.append(column_major(remainder.astype(mu.dtype)))
     if scheduled:
         in_specs.append(col)                    # global prev-mass column
-        operands.append(prev_mass.astype(mu.dtype))
+        operands.append(column_major(prev_mass.astype(mu.dtype)))
     in_specs += [mu_spec, full((Dp, Kp)), full((Wrows, Kp)), full((1, Kp))]
     operands += [mu_cols, theta, phi_wk, phi_k[None, :]]
 
@@ -513,13 +505,18 @@ def sharded_fold_pallas(
         jax.ShapeDtypeStruct((1, Kp), phi_k.dtype),
         jax.ShapeDtypeStruct((L, Dp, Kp), mu.dtype),
         jax.ShapeDtypeStruct((L, Dp, Kp), mu.dtype),
-        jax.ShapeDtypeStruct((Dp, L), mu.dtype),
+        jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype),
     ]
     if emit_loglik:
-        out_specs.append(col)                               # pre-log partials
-        out_shape.append(jax.ShapeDtypeStruct((Dp, L), mu.dtype))
+        out_specs.append(                                   # pre-log partials
+            pl.BlockSpec((1, Dp, 1), lambda l, *p: (ll_of(l), 0, 0))
+        )
+        out_shape.append(jax.ShapeDtypeStruct((L, Dp, 1), mu.dtype))
 
-    scratch_shapes = [pltpu.VMEM((Dp, Kp), mu.dtype)]        # gathered rows
+    scratch_shapes = [
+        pltpu.VMEM((Dp, Kp), mu.dtype),                     # gathered rows
+        pltpu.VMEM((Dp, Kp), mu.dtype),                     # staged Δ rows
+    ]
     if scheduled:
         scratch_shapes.append(pltpu.VMEM((Dp, Kp), mu.dtype))  # lane mask
 
@@ -534,7 +531,7 @@ def sharded_fold_pallas(
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    prefetch = (word_ids, word_topics, wb_arr) if scheduled else (
+    prefetch = (word_ids, word_topics.reshape(-1), wb_arr) if scheduled else (
         word_ids, wb_arr
     )
     outs = pl.pallas_call(
@@ -543,18 +540,16 @@ def sharded_fold_pallas(
         out_shape=out_shape,
         input_output_aliases={theta_idx: 0, theta_idx + 1: 1,
                               theta_idx + 2: 2},
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*prefetch, *operands)
 
     theta_out, phi_out, ptot_out, mu_out, res_out, m_out = outs[:6]
-    u = outs[6][:D] if emit_loglik else None
+    u = from_column_major(outs[6])[:D] if emit_loglik else None
 
     mu_new = mu_out.transpose(1, 0, 2)[:D, :, :K]
     res = res_out.transpose(1, 0, 2)[:D, :, :K]
     return (
         mu_new, res, theta_out[:D, :K], phi_out[:, :K], ptot_out[0, :K],
-        m_out[:D], u,
+        from_column_major(m_out)[:D], u,
     )

@@ -77,6 +77,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.budget import DEFAULT_VMEM_BUDGET
 from repro.analysis.checks import kernel_fits_vmem
+from repro.kernels.gs_sweep import (
+    column_major,
+    compiler_params,
+    from_column_major,
+)
+from repro.kernels.scheduled_sweep import expand_lane_mask
 
 
 #: Serving φ storage dtypes ``ops.infer`` accepts (InferPlan.phi_dtype).
@@ -145,7 +151,8 @@ def dequantize_phi(values: jax.Array,
 
 def _make_theta_kernel(*, alpha_m1: float, k_actual: int, num_cols: int,
                        num_sweeps: int, active_topics: int,
-                       quantized: bool = False, has_scale: bool = False):
+                       quantized: bool = False, has_scale: bool = False,
+                       row_tile: int = 1):
     """Kernel body for a static (sweeps, A, φ-dtype) configuration.
 
     Ref order: scalar prefetch (wid[, word-topics][, φ row scales]),
@@ -155,6 +162,12 @@ def _make_theta_kernel(*, alpha_m1: float, k_actual: int, num_cols: int,
     dense variant; ``quantized`` casts each gathered φ row back to f32 on
     read (``has_scale`` additionally multiplies by the word's
     scalar-prefetched int8 scale) — the f32 variant stages no cast at all.
+
+    A packed φ (bf16/int8) cannot be loaded one row at a dynamic offset:
+    the compiler needs the offset aligned to the dtype's sublane tile.
+    The quantized gather loads the ``row_tile``-row tile holding the word
+    and keeps the word's row with a sublane select (exact: one value and
+    zeros summed).
     """
     scheduled = active_topics > 0
 
@@ -190,26 +203,31 @@ def _make_theta_kernel(*, alpha_m1: float, k_actual: int, num_cols: int,
 
             def go(d, _):
                 w = wid_ref[d, col]
-                row = phi_ref[pl.ds(w, 1), :]
                 if quantized:
                     # dequantize on read: the f32 rows scratch receives
                     # exact f32 arithmetic from here on
-                    row = row.astype(rows_ref.dtype)
+                    base = pl.multiple_of((w // row_tile) * row_tile, row_tile)
+                    tile = phi_ref[pl.ds(base, row_tile), :].astype(
+                        rows_ref.dtype
+                    )
+                    sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+                    row = jnp.where(sub == w - base, tile, 0.0).sum(
+                        0, keepdims=True
+                    )
                     if has_scale:
                         row = row * scale_ref[w]
+                else:
+                    row = phi_ref[pl.ds(w, 1), :]
                 rows_ref[pl.ds(d, 1), :] = row
                 if with_mask:
-                    m = jnp.zeros((1, K), theta_in_ref.dtype)
-                    for a in range(active_topics):  # static unroll, A ≈ 16
-                        m = jnp.maximum(
-                            m, (lane == wtop_ref[w, a]).astype(m.dtype)
-                        )
-                    mask_ref[pl.ds(d, 1), :] = m
+                    mask_ref[pl.ds(d, 1), :] = expand_lane_mask(
+                        wtop_ref, w, active_topics, lane, theta_in_ref.dtype
+                    )
                 return 0
             jax.lax.fori_loop(0, D, go, 0)
 
         def sweep_col():
-            cnt = cnt_ref[...]                  # (D, 1)
+            cnt = cnt_ref[0]                    # (D, 1)
             th_n = theta_norm()
             gather(scheduled)
             num = th_n * rows_ref[...]
@@ -232,17 +250,14 @@ def _make_theta_kernel(*, alpha_m1: float, k_actual: int, num_cols: int,
             def _():
                 theta_ref[...] = acc_ref[...]
 
-            est_ref[0] = jnp.zeros((D, 1), theta_in_ref.dtype)
-            evll_ref[0] = jnp.zeros((D, 1), theta_in_ref.dtype)
-
         def eval_col():
             # eq. 21 phase against the FINAL θ̂, full topic support (the
             # scheduled variant restricts only the fit, never the score)
             gather(False)
             lik = (theta_norm() * rows_ref[...]).sum(-1, keepdims=True)
             ll = jnp.log(jnp.maximum(lik, 1e-30))
-            est_ref[0] = cnt_ref[...] * ll      # eq. 3 stop-rule partial
-            evll_ref[0] = ev_ref[...] * ll      # eq. 21 partial
+            est_ref[0] = cnt_ref[0] * ll        # eq. 3 stop-rule partial
+            evll_ref[0] = ev_ref[0] * ll        # eq. 21 partial
 
         @pl.when(l < num_sweeps * num_cols)
         def _():
@@ -312,10 +327,19 @@ def theta_sweep_pallas(
         ev_counts = jnp.pad(ev_counts, ((0, pad_d), (0, 0)))
         theta = jnp.pad(theta, ((0, pad_d), (0, pad_k)))
         phi_norm = jnp.pad(phi_norm, ((0, 0), (0, pad_k)))
+    # a quantized gather reads whole sublane tiles: pad W_s to the tile
+    row_tile = PHI_SUBLANE[jnp.dtype(phi_norm.dtype).name] if quantized else 1
+    pad_w = (-Wrows) % row_tile
+    if pad_w:
+        phi_norm = jnp.pad(phi_norm, ((0, pad_w), (0, 0)))
+        Wrows += pad_w
+        if has_scale:
+            phi_scale = jnp.pad(phi_scale, ((0, pad_w),), constant_values=1.0)
 
     kernel = _make_theta_kernel(
         alpha_m1=alpha_m1, k_actual=K, num_cols=L, num_sweeps=num_sweeps,
         active_topics=A, quantized=quantized, has_scale=has_scale,
+        row_tile=row_tile,
     )
     grid_len = num_sweeps * L + L              # sweeps + eq. 21 columns
 
@@ -324,17 +348,20 @@ def theta_sweep_pallas(
         return lambda l, *scalars: fn(l)
 
     col_of = lambda l: jax.lax.rem(l, L)
+    # the eq. 21 outputs hold block 0 through the sweeps, which never write
+    # it, then walk the columns once: no output block is revisited
+    eval_of = lambda l: jnp.maximum(l - num_sweeps * L, 0)
 
     in_specs = [
-        pl.BlockSpec((Dp, 1), idx(lambda l: (0, col_of(l)))),
-        pl.BlockSpec((Dp, 1), idx(lambda l: (0, col_of(l)))),
+        pl.BlockSpec((1, Dp, 1), idx(lambda l: (col_of(l), 0, 0))),
+        pl.BlockSpec((1, Dp, 1), idx(lambda l: (col_of(l), 0, 0))),
         pl.BlockSpec((Dp, Kp), idx(lambda l: (0, 0))),
         pl.BlockSpec((Wrows, Kp), idx(lambda l: (0, 0))),
     ]
     out_specs = [
         pl.BlockSpec((Dp, Kp), idx(lambda l: (0, 0))),
-        pl.BlockSpec((1, Dp, 1), idx(lambda l: (col_of(l), 0, 0))),
-        pl.BlockSpec((1, Dp, 1), idx(lambda l: (col_of(l), 0, 0))),
+        pl.BlockSpec((1, Dp, 1), idx(lambda l: (eval_of(l), 0, 0))),
+        pl.BlockSpec((1, Dp, 1), idx(lambda l: (eval_of(l), 0, 0))),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((Dp, Kp), theta.dtype),
@@ -350,11 +377,12 @@ def theta_sweep_pallas(
 
     operands = [word_ids]
     if scheduled:
-        operands.append(word_topics)
+        operands.append(word_topics.reshape(-1))
     if has_scale:
         operands.append(phi_scale)
     n_scalars = len(operands)
-    operands += [est_counts, ev_counts, theta, phi_norm]
+    operands += [column_major(est_counts), column_major(ev_counts), theta,
+                 phi_norm]
     # flat operands: wid(0) [wtop] [scale] est ev theta phi — θ̂ donated
     theta_idx = n_scalars + 2
 
@@ -370,12 +398,10 @@ def theta_sweep_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases={theta_idx: 0},
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
 
-    est_ll = est_out[..., 0].T[:D]             # (D, L) per-token partials
-    ev_ll = ev_out[..., 0].T[:D]
+    est_ll = from_column_major(est_out)[:D]    # (D, L) per-token partials
+    ev_ll = from_column_major(ev_out)[:D]
     return theta_out[:D, :K], est_ll, ev_ll

@@ -47,6 +47,7 @@ from repro.core import (
 from repro.core.perplexity import split_heldout_counts
 from repro.data import synthetic_lda_corpus
 from repro.launch.serve import ServingEngine, TopicServer, TrafficGenerator
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sparse import MinibatchStream
 from repro.sparse.docword import bucketize
 
@@ -216,6 +217,7 @@ def run_lifelong(
 
 
 def main(argv: Optional[List[str]] = None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workdir", default="/tmp/repro_lifelong")
     ap.add_argument("--topics", type=int, default=32)

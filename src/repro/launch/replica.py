@@ -430,8 +430,9 @@ class ReplicaPool:
     and fault architecture.
 
     Parameters beyond the router's: ``backend`` ("process" spawns one
-    worker process per replica; "thread" runs in-process replicas, the
-    device-mesh degenerate case), ``max_inflight`` (per-replica dispatch
+    worker process per replica and refuses to start on a TPU host, where
+    the parent holds the chips; "thread" runs in-process replicas, one
+    device each), ``max_inflight`` (per-replica dispatch
     cap — the balancer's least-loaded window), ``respawn`` (replace dead
     workers; ``False`` downsizes instead), and ``servers`` (thread
     backend only: prebuilt ``TopicServer``s, e.g. sharing the owning
@@ -449,6 +450,19 @@ class ReplicaPool:
             raise ValueError(f"unknown replica backend {backend!r}")
         if backend == "process" and spec is None:
             raise ValueError("process backend needs a picklable ReplicaSpec")
+        if backend == "process":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                # this process now holds the chips; a spawned worker that
+                # initialises JAX would fail or hang waiting for them
+                raise RuntimeError(
+                    "the process replica backend cannot run on a TPU host: "
+                    "the parent process holds the chips and spawned "
+                    "workers cannot open them. Use backend=\"thread\" "
+                    "(serve CLI: --replica-backend thread), which pins one "
+                    "device per replica in this process."
+                )
         if servers is not None and backend != "thread":
             raise ValueError("prebuilt servers are thread-backend only")
         if servers is not None and len(servers) != replicas:

@@ -63,7 +63,14 @@ from repro.core.types import InferPlan, MinibatchData, uniform_responsibilities
 from repro.data import synthetic_lda_corpus
 from repro.kernels import ops as kops
 from repro.models import build
-from repro.sparse.docword import DocWordMatrix, bucketize, localize_vocab
+from repro.runtime.compile_cache import enable_compile_cache
+from repro.sparse.docword import (
+    VOCAB_BUCKET,
+    DocWordMatrix,
+    bucketize,
+    localize_vocab,
+    pad_vocab_rows,
+)
 
 
 def _round_up(n: int, multiple: int) -> int:
@@ -184,7 +191,7 @@ class TopicServer:
                  active_topics: int = 0,
                  use_pallas: Optional[bool] = None,
                  interpret: bool = False,
-                 vocab_pad: int = 512,
+                 vocab_pad: int = VOCAB_BUCKET,
                  phi_dtype: str = "float32",
                  hot_rows: int = 0):
         self.store = store
@@ -289,11 +296,7 @@ class TopicServer:
         rows = self._fetch_rows(uniq, active)              # streamed φ̂
         # pad the local vocab to a bucket boundary so jit traces are reused
         # across requests (padded rows are never indexed by `local`)
-        pad = _round_up(len(uniq), self.vocab_pad) - len(uniq)
-        if pad:
-            rows = np.concatenate(
-                [rows, np.zeros((pad, rows.shape[1]), rows.dtype)]
-            )
+        rows = pad_vocab_rows(rows, self.vocab_pad)
         args = (
             key, jnp.asarray(local), jnp.asarray(counts),
             jnp.asarray(
@@ -924,7 +927,7 @@ def serve_traffic(args, server: TopicServer) -> None:
             active_topics=server.active_topics, vocab_pad=server.vocab_pad,
             phi_dtype=server.phi_dtype, hot_rows=args.hot_rows,
         )
-        backend = getattr(args, "replica_backend", "process")
+        backend = getattr(args, "replica_backend", "thread")
         with ReplicaPool(spec, replicas=replicas, backend=backend,
                          max_batch=args.batch,
                          max_delay_ms=args.max_delay_ms,
@@ -1041,6 +1044,7 @@ def serve_lm(args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=LDA_ARCH)
     ap.add_argument("--workdir", default="/tmp/repro_train")
@@ -1074,11 +1078,12 @@ def main() -> None:
                     help="serve --traffic through a ReplicaPool of N "
                          "data-parallel workers (1 = the single-replica "
                          "engine)")
-    ap.add_argument("--replica-backend", default="process",
+    ap.add_argument("--replica-backend", default="thread",
                     choices=("process", "thread"),
-                    help="replica isolation: one spawned process per "
-                         "replica (scales past the GIL) or in-process "
-                         "threads (the device-mesh degenerate case)")
+                    help="replica isolation: in-process threads, one "
+                         "device each (the only backend on a TPU host, "
+                         "where one process holds the chips) or one "
+                         "spawned process per replica (CPU hosts)")
     args = ap.parse_args()
     if args.arch == LDA_ARCH:
         serve_lda(args)

@@ -30,6 +30,7 @@ from repro.core.types import MinibatchData
 from repro.data import synthetic_lda_corpus, synthetic_token_stream
 from repro.models import build
 from repro.optim import adamw_init, adamw_update, cosine_warmup
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sparse import MinibatchStream
 from repro.sparse.docword import bucketize
 
@@ -137,6 +138,7 @@ def train_lm(args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=LDA_ARCH)
     ap.add_argument("--workdir", default="/tmp/repro_train")

@@ -1,36 +1,25 @@
-"""jax API compatibility shims for the pinned toolchain (jax 0.4.37).
-
-The distributed tier targets the modern spellings — ``jax.shard_map``,
-``jax.sharding.AxisType``, ``AbstractMesh(axis_sizes, axis_names)`` — but the
-pinned CI/runtime jax (0.4.37) predates all three: ``shard_map`` still lives
-in ``jax.experimental.shard_map`` with the replication check spelled
-``check_rep`` (renamed ``check_vma`` later), ``make_mesh`` takes no
-``axis_types``, and ``AbstractMesh`` takes a ``((name, size), ...)`` tuple.
+"""The library's shard_map and mesh spellings, in one place.
 
 Every shard_map/mesh construction in the library and the distributed tests
-routes through this module so the code runs unchanged on both API
-generations.  Nothing here changes semantics: the explicit-sharding
-``AxisType`` machinery is only ever requested as ``Auto`` (the 0.4.37
-default), and the replication check is disabled on both spellings.
+routes through this module, so the choices below are made once:
+
+* the replication check is off — the FOEM collectives produce
+  deliberately device-varying intermediates that the checker rejects;
+* every mesh axis is ``AxisType.Auto``, pinned explicitly so a change of
+  JAX's default axis type cannot flip the library's collectives.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 from jax.sharding import AbstractMesh, Mesh
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on old.
+    """``jax.shard_map`` with ``check`` mapped to ``check_vma``.
 
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old); the library
-    always passes False — the FOEM collectives produce deliberately
-    device-varying intermediates that the replication checker rejects.
     Usable directly or as a decorator (``f=None``).
     """
     if f is None:
@@ -38,57 +27,28 @@ def shard_map(f=None, *, mesh, in_specs, out_specs, check: bool = False):
             shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check=check,
         )
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check,
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check,
     )
 
 
 def pvary(x, axis_name):
-    """``lax.pvary`` where it exists, identity elsewhere.
-
-    ``pvary`` only annotates device-varyingness for the new replication
-    checker; with the check disabled (the only mode this library uses on
-    0.4.37) it has no runtime effect.
-    """
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_name)
-    return x
+    """Mark ``x`` device-varying over ``axis_name`` (``lax.pcast``)."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with every axis ``Auto`` where the API exists.
-
-    0.4.37's ``make_mesh`` has no ``axis_types`` parameter (everything is
-    implicitly auto-sharded); newer jax defaults new meshes the same way but
-    we pin ``Auto`` explicitly so the explicit-sharding migration can't flip
-    the library's collectives underneath us.
-    """
+    """``jax.make_mesh`` with every axis ``Auto``."""
     kwargs = {} if devices is None else {"devices": devices}
-    if _HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (
-            jax.sharding.AxisType.Auto,
-        ) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        **kwargs,
+    )
 
 
 def abstract_mesh(axis_shapes: Sequence[int],
                   axis_names: Sequence[str]) -> AbstractMesh:
-    """Device-free mesh for sharding-rule unit tests, both constructor ABIs.
-
-    New jax: ``AbstractMesh(axis_sizes, axis_names)``.  0.4.37:
-    ``AbstractMesh(((name, size), ...))``.
-    """
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(
-            tuple(zip(tuple(axis_names), tuple(axis_shapes)))
-        )
+    """Device-free mesh for sharding-rule unit tests."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))

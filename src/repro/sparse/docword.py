@@ -122,6 +122,24 @@ def bucketize(
     return word_ids, counts
 
 
+#: W_s bucket of the trainer's and server's jit shapes: a multiple of the
+#: f32 sublane tile (8), so the Pallas kernels are eligible, and coarse
+#: enough that a stream of minibatches reuses a few compiled shapes.
+VOCAB_BUCKET = 512
+
+
+def pad_vocab_rows(rows: np.ndarray, multiple: int = VOCAB_BUCKET) -> np.ndarray:
+    """Append zero rows to a (W_s, K) block up to the next ``multiple``.
+
+    The padding rows are never indexed by the minibatch's local word ids;
+    callers drop them again before writing rows back.
+    """
+    pad = (-len(rows)) % max(1, multiple)
+    if not pad:
+        return rows
+    return np.concatenate([rows, np.zeros((pad,) + rows.shape[1:], rows.dtype)])
+
+
 def localize_vocab(
     word_ids: np.ndarray,
 ) -> Tuple[np.ndarray, np.ndarray]:

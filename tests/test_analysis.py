@@ -206,6 +206,30 @@ def test_lane_misalignment_caught():
     assert any("lane" in e for e in rep.errors)
 
 
+def test_column_block_over_token_matrix_is_misaligned():
+    """The per-column (Dp, 1) block over a (Dp, L) operand — the layout the
+    TPU compiler refused — is an alignment error; the column-major
+    (1, Dp, 1) block over (L, Dp, 1) the kernels use is not."""
+    spec = _gs_spec()
+    col = spec.inputs[0]
+    L, Dp = col.full_shape[0], col.full_shape[1]
+    old = dataclasses.replace(col, block_shape=(Dp, 1), full_shape=(Dp, L),
+                              max_index=(0, L - 1))
+    rep = check_spec(dataclasses.replace(spec, inputs=(old,) + spec.inputs[1:]))
+    assert any("minor block dim 1" in e for e in rep.errors), rep.errors
+    assert check_spec(spec).errors == ()
+
+
+def test_smem_pads_two_dimensional_tables():
+    """A 2-D scalar-prefetch table pads to the (8, 128) tile: the (W_s, A)
+    active-topic table would need 4 MiB of the 1 MiB scalar memory at the
+    reference cell, its flattened form 512 KiB."""
+    table = bm.Scalar("word_topics", (8192, 16))
+    flat = bm.Scalar("word_topics", (8192 * 16,))
+    assert table.smem_bytes() == 8192 * 128 * 4 > bm.DEFAULT_SMEM_BUDGET
+    assert flat.smem_bytes() == 8192 * 16 * 4 < bm.DEFAULT_SMEM_BUDGET
+
+
 def test_check_all_reports_dominating_term():
     big = bm.Cell(D=1024, L=64, K=256, W_s=32768, A=16)
     reports = check_all([("big", big)])

@@ -275,3 +275,62 @@ def test_traced_vocab_size_reaches_kernels():
     ).phi_wk
     np.testing.assert_allclose(np.asarray(traced), np.asarray(eager),
                                atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Dispatch record: which path a trace took, and why
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case, path, reason", [
+    ("auto", "portable", "no TPU"),
+    ("forced_portable", "portable", "forced"),
+    ("forced_interpret", "interpret", "forced"),
+    ("tpu_fits", "pallas", "auto"),
+    ("tpu_vmem", "portable", "VMEM"),
+    ("tpu_ragged", "portable", "sublane"),
+])
+def test_sweep_dispatch_log_records_path_and_reason(monkeypatch, case, path,
+                                                    reason):
+    """``ops.sweep`` records each trace-time choice between a kernel and
+    the portable path with its reason (traced only: ``eval_shape``)."""
+    W = 13 if case == "tpu_ragged" else 16
+    batch, local, phi, ptot = _state(4, 3, 8, W)
+    kw = dict(alpha_m1=0.01, beta_m1=0.01, wb=W * 0.01)
+    if case == "forced_portable":
+        kw["use_pallas"] = False
+    elif case == "forced_interpret":
+        kw["interpret"] = True
+    if case.startswith("tpu"):
+        monkeypatch.setattr(kops, "on_tpu", lambda: True)
+        monkeypatch.setattr(kops, "fits_vmem",
+                            lambda *a: case != "tpu_vmem")
+    mark = max([d.seq for d in kops.dispatch_log()], default=-1)
+    jax.eval_shape(
+        lambda *a: kops.sweep(*a, **kw),
+        batch.word_ids, batch.counts, local.mu, local.theta_dk, phi, ptot,
+    )
+    (got,) = kops.dispatch_log(since=mark)
+    assert (got.entry, got.path, got.reason) == ("sweep", path, reason)
+    assert got.shape == (4, 3, 8, W)
+    assert str(got) == f"sweep {path}: {reason}"
+
+
+@pytest.mark.parametrize("vmem_fits", [True, False])
+def test_infer_dispatch_log_records_path_and_reason(monkeypatch, vmem_fits):
+    """``ops.infer`` records its kernel-or-portable choice the same way."""
+    rng = np.random.default_rng(0)
+    D, L, K, W = 4, 3, 8, 16
+    wid = jnp.asarray(rng.integers(0, W, (D, L)), jnp.int32)
+    cnt = jnp.ones((D, L), jnp.float32)
+    phi = jnp.asarray(rng.dirichlet(np.ones(W), K).T, jnp.float32)
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    monkeypatch.setattr(kops, "theta_fits_vmem", lambda *a, **k: vmem_fits)
+    mark = max([d.seq for d in kops.dispatch_log()], default=-1)
+    jax.eval_shape(
+        lambda w, c, t, p: kops.infer(w, c, t, p, alpha_m1=0.01,
+                                      max_sweeps=10),
+        wid, cnt, jnp.zeros((D, K), jnp.float32), phi,
+    )
+    (got,) = kops.dispatch_log(since=mark)
+    want = ("pallas", "auto") if vmem_fits else ("portable", "VMEM")
+    assert (got.entry, got.path, got.reason) == ("infer",) + want

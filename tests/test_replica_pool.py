@@ -449,3 +449,13 @@ def test_process_pool_soft_kill_reissue(pool_store, pool_docs):
         m = pool.metrics()
     assert len(got) == len(pool_docs)
     assert m["deaths"] == 1 and m["respawns"] == 1
+
+
+def test_process_backend_refuses_tpu_host(monkeypatch, pool_store):
+    """On a TPU host the parent process holds the chips, so the process
+    backend refuses to start and names the thread backend instead."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match='backend="thread"'):
+        ReplicaPool(_spec(pool_store), replicas=2, backend="process")

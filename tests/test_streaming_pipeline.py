@@ -144,3 +144,78 @@ def test_prefetch_iterator_abandonment_stops_worker():
         _time.sleep(0.05)
     assert not any(t.name == "minibatch-prefetch" and t.is_alive()
                    for t in threading.enumerate())
+
+
+# ---------------------------------------------------------------------------
+# W_s bucketing: the trainer pads the streamed rows to a jit-shape bucket
+# ---------------------------------------------------------------------------
+
+def test_padded_trainer_step_matches_unpadded_bitwise(tmp_path):
+    """The trainer pads the (W_s, K) rows with zero rows to a multiple of
+    ``docword.VOCAB_BUCKET``; on the portable path the step it writes back
+    is bitwise the unpadded ``foem_minibatch`` step: same φ̂ rows, same
+    φ̂(k), same train ppl (λ_w < 1 also exercises the word ranking)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import foem
+    from repro.core.types import MinibatchData
+    from repro.sparse.docword import VOCAB_BUCKET
+
+    corpus, _ = synthetic_lda_corpus(80, 150, 5, mean_doc_len=30, seed=3)
+    cfg = LDAConfig(num_topics=5, vocab_size=150, max_sweeps=6,
+                    active_topics=2, active_words_frac=0.7,
+                    ppl_check_every=2)
+    store = ParameterStore(str(tmp_path / "pad"), num_topics=5,
+                           vocab_capacity=150)
+    tr = FOEMTrainer(cfg, store, seed=0, prefetch_depth=0)
+    stream = iter(MinibatchStream(corpus, 40, seed=0, epochs=None))
+    tr.step(next(stream))                 # a non-trivial φ̂ for step two
+    mb = next(stream)
+    assert len(mb.local_vocab) % VOCAB_BUCKET    # the step really pads
+
+    rows = store.fetch_rows(mb.local_vocab)
+    phi_k = store.phi_k.astype(np.float32)
+    _, sub = jax.random.split(tr.key)
+
+    @jax.jit
+    def unpadded(key, batch, rows, phi_k, live_w):
+        res = foem.foem_minibatch(key, batch, rows, phi_k, cfg,
+                                  vocab_size=live_w)
+        return res.phi_wk, res.phi_k, res.diag.final_train_ppl
+
+    want_rows, want_k, want_ppl = unpadded(
+        sub, MinibatchData(jnp.asarray(mb.local_word_ids),
+                           jnp.asarray(mb.counts)),
+        jnp.asarray(rows), jnp.asarray(phi_k), max(store.live_vocab, cfg.W),
+    )
+    m = tr.step(mb)
+    np.testing.assert_array_equal(store.fetch_rows(mb.local_vocab),
+                                  np.asarray(want_rows))
+    np.testing.assert_array_equal(store.phi_k.astype(np.float32),
+                                  np.asarray(want_k))
+    assert m.train_ppl == float(want_ppl)
+
+
+def test_varying_ws_stream_compiles_once_per_bucket(tmp_path):
+    """Minibatches with different unique-vocabulary sizes share one
+    compiled step per W_s bucket instead of compiling per step."""
+    from repro.sparse.docword import VOCAB_BUCKET
+
+    corpus, _ = synthetic_lda_corpus(160, 900, 5, mean_doc_len=30, seed=5)
+    cfg = LDAConfig(num_topics=5, vocab_size=900, max_sweeps=2)
+    store = ParameterStore(str(tmp_path / "buckets"), num_topics=5,
+                           vocab_capacity=900)
+    tr = FOEMTrainer(cfg, store, seed=0, prefetch_depth=0)
+    sizes = []
+
+    def stream():
+        for mb in MinibatchStream(corpus, 20, seed=0, epochs=None):
+            sizes.append(len(mb.local_vocab))
+            yield mb
+
+    tr.fit_stream(stream(), max_steps=6)
+    buckets = {-(-n // VOCAB_BUCKET) for n in sizes}
+    assert len(set(sizes)) > len(buckets)        # W_s varied within buckets
+    compiled = sum(fn._cache_size() for fn in tr._jit_cache.values())
+    assert compiled == len(buckets)

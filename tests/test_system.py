@@ -108,3 +108,24 @@ def test_foem_matches_sem_quality_with_less_work(tiny_corpus):
     p_foem = run(foem.foem_step, cfg_foem)
     p_sem = run(sem.sem_step, cfg_sem)
     assert p_foem < p_sem * 1.3, (p_foem, p_sem)
+
+
+def test_compile_cache_env_wins_else_checkout_dir(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX untouched; unset, the
+    entry points cache at the fixed ``<checkout>/.jax_cache``."""
+    import os
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(checkout, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
