@@ -64,6 +64,7 @@ from typing import Iterable, Iterator, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from repro.runtime import faults as fault_lib
+from repro.runtime.spans import span
 
 
 class StoreCorruptionError(RuntimeError):
@@ -148,6 +149,28 @@ class StoreStats:
         return dataclasses.replace(self)
 
 
+class _TimedLock:
+    """A re-entrant lock whose contended acquires are timed.  Only an
+    acquire that has to block reads the clock: it runs under span
+    ``store.lock_wait`` and its seconds go to the waiting thread's
+    ``wait_seconds()``."""
+
+    def __init__(self):
+        self._lock = threading.RLock()
+        self._waits = threading.local()
+
+    def __enter__(self) -> None:
+        if not self._lock.acquire(blocking=False):
+            with span("store.lock_wait", self._waits.__dict__, "seconds"):
+                self._lock.acquire()
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+    def wait_seconds(self) -> float:
+        return getattr(self._waits, "seconds", 0.0)
+
+
 class ParameterStore:
     """Disk-backed φ̂_{W×K} with a write-back LRU hot-word buffer.
 
@@ -160,8 +183,10 @@ class ParameterStore:
 
     Thread safety: every public mutator takes ``_lock`` so a background
     prefetcher (``StreamPrefetcher``) can fetch while the trainer writes
-    back.  ``write_version`` increments on every value-changing write; a
-    fetch tagged with an older version may miss those writes and must be
+    back.  A contended acquire is timed (span ``store.lock_wait``) and
+    charged to the thread that waited: ``lock_wait_seconds()``.
+    ``write_version`` increments on every value-changing write; a fetch
+    tagged with an older version may miss those writes and must be
     reconciled by the caller (see ``fetch_rows_versioned``).
 
     Row ids within one ``fetch_rows``/``write_rows`` call must be unique —
@@ -220,7 +245,7 @@ class ParameterStore:
         # readonly attach: committed-but-unapplied WAL rows, overlaid on
         # fetches in memory (sorted ids + rows) — disk is never touched
         self._overlay: Optional[Tuple[np.ndarray, np.ndarray]] = None
-        self._lock = threading.RLock()
+        self._lock = _TimedLock()
         # ---- array-backed LRU (empty slots carry id == -1) ----
         W_star = self.buffer_rows
         self._buf = np.zeros((W_star, self.K), self.dtype)
@@ -290,6 +315,11 @@ class ParameterStore:
                 self.recovered_from_wal = True
                 return
         self._load_manifest()
+
+    def lock_wait_seconds(self) -> float:
+        """Seconds the calling thread has spent blocked on the store lock
+        (cumulative; a step reads the difference)."""
+        return self._lock.wait_seconds()
 
     def _check_writable(self) -> None:
         if self.readonly:
@@ -1105,7 +1135,8 @@ class HotRowCache:
 
 class PrefetchedBatch(NamedTuple):
     """A minibatch staged by the worker: its φ̂ rows, the store version the
-    fetch is consistent with, and how long the host I/O took."""
+    fetch is consistent with, and how long the host I/O took (span
+    ``foem.fetch``, any store lock wait included)."""
 
     minibatch: object            # sparse.minibatch.Minibatch
     phi_rows: np.ndarray         # (W_s, K)
@@ -1128,6 +1159,9 @@ class StreamPrefetcher:
     the consumer patches rows overlapping any newer write-back (the
     trainer keeps the last few write sets) — that reconciliation is what
     makes prefetched and sequential execution bitwise-identical.
+
+    The worker's stages are spans ``foem.next_minibatch`` (the stream's
+    bucketize and localize) and ``foem.fetch``.
     """
 
     def __init__(self, store: ParameterStore, stream: Iterable, depth: int = 1):
@@ -1138,25 +1172,22 @@ class StreamPrefetcher:
         from repro.sparse.minibatch import prefetch_iterator
 
         def staged() -> Iterator[PrefetchedBatch]:
-            for mb in stream:
-                t0 = time.perf_counter()
-                rows, version = store.fetch_rows_versioned(mb.local_vocab)
-                yield PrefetchedBatch(
-                    mb, rows, version, time.perf_counter() - t0
-                )
+            it = iter(stream)
+            while True:
+                with span("foem.next_minibatch"):
+                    mb = next(it, None)
+                if mb is None:
+                    return
+                with span("foem.fetch") as fetch:
+                    rows, version = store.fetch_rows_versioned(mb.local_vocab)
+                yield PrefetchedBatch(mb, rows, version, fetch.seconds)
 
         self._inner = prefetch_iterator(staged(), depth=depth)
 
-    def __iter__(self) -> Iterator[Tuple[PrefetchedBatch, float]]:
-        """Yields ``(staged_batch, wait_seconds)`` — wait_seconds is how long
-        the consumer blocked on the queue (≈0 ⇒ the fetch fully overlapped)."""
-        while True:
-            t0 = time.perf_counter()
-            try:
-                item = next(self._inner)
-            except StopIteration:
-                return
-            yield item, time.perf_counter() - t0
+    def __iter__(self) -> Iterator[PrefetchedBatch]:
+        """Yields the staged batches in stream order (the consumer times
+        its own wait on the queue)."""
+        return self._inner
 
     def close(self) -> None:
         """Stop the worker and release the source (safe to call repeatedly)."""

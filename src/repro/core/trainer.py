@@ -20,7 +20,6 @@ space bound with W* = buffer_rows.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -32,12 +31,16 @@ from repro.core import em, foem, sem
 from repro.core.streaming import ParameterStore, StreamPrefetcher
 from repro.core.types import GlobalStats, LDAConfig, MinibatchData
 from repro.runtime import faults as fault_lib
+from repro.runtime.spans import span
 from repro.sparse.docword import pad_vocab_rows
 from repro.sparse.minibatch import Minibatch, MinibatchStream
 
 
 @dataclasses.dataclass
 class StepMetrics:
+    """One step's record.  ``seconds`` is span ``foem.step``; the host-stage
+    fields are 0 for a dropped step."""
+
     step: int
     sweeps: int
     train_ppl: float
@@ -50,7 +53,11 @@ class StepMetrics:
     residual_mass: float = float("nan")  # eq. 36 Σ r_w at sweep exit (foem)
     published_version: int = -1     # φ snapshot published at this step (-1: none)
     shift_events: Tuple = ()        # ShiftEvents the detector fired this step
-    scheduler_refresh: bool = False  # step ran with extra warm-up sweeps
+    fetch_seconds: float = 0.0      # foem.fetch of this step's rows (any thread)
+    lock_wait_seconds: float = 0.0  # trainer thread blocked on the store lock
+    host_seconds: float = 0.0       # foem.step minus foem.device_wait
+    h2d_bytes: int = 0              # host arrays passed into the step program
+    d2h_bytes: int = 0              # arrays device_get returned
 
 
 class FOEMTrainer:
@@ -177,9 +184,12 @@ class FOEMTrainer:
 
     def step(self, mb: Minibatch) -> StepMetrics:
         """Synchronous step: fetch → compute → write back."""
-        t0 = time.perf_counter()
-        phi_rows = self.store.fetch_rows(mb.local_vocab)           # (W_s, K)
-        return self._step_with_rows(mb, phi_rows, t0=t0)[0]
+        lock0 = self.store.lock_wait_seconds()
+        with span("foem.step") as whole:
+            with span("foem.fetch") as fetch:
+                phi_rows = self.store.fetch_rows(mb.local_vocab)   # (W_s, K)
+            m, _, waited = self._step_with_rows(mb, phi_rows)
+        return self._timed(m, whole.seconds, waited, fetch.seconds, lock0)
 
     def _step_with_rows(
         self,
@@ -188,56 +198,54 @@ class FOEMTrainer:
         *,
         prefetch_hit: bool = False,
         overlap_seconds: float = 0.0,
-        t0: Optional[float] = None,
-    ) -> Tuple[StepMetrics, np.ndarray]:
+    ) -> Tuple[StepMetrics, np.ndarray, float]:
         """Run the jitted inner loop on pre-fetched rows and write back.
 
-        Returns ``(metrics, new_rows)`` — new_rows feed the prefetch
-        reconciliation log.  ``t0`` is when the step's host I/O started
-        (the fetch in the sync path, the queue wait in the pipelined
-        path) so ``StepMetrics.seconds`` covers fetch + compute + write
-        back in both.  I/O counters are per-step deltas of the store's
-        cumulative stats; in the pipelined path a step's delta includes
-        the *next* minibatch's background fetch (sums over the run are
-        exact either way).
+        Returns ``(metrics, new_rows, device_wait_seconds)`` — new_rows
+        feed the prefetch reconciliation log.  It runs inside the caller's
+        ``foem.step`` span, which sets the timings with :meth:`_timed`.
+        I/O counters are per-step deltas of the store's cumulative stats;
+        in the pipelined path a step's delta includes the *next*
+        minibatch's background fetch (sums over the run are exact either
+        way).
         """
         cfg = self.cfg
-        if t0 is None:
-            t0 = time.perf_counter()
         # pre-probe: a "kill" raises before any state is touched; a "drop"
         # skips this minibatch entirely (contribution lost → re-issue queue)
         if self.faults is not None and self.faults.fire(
             fault_lib.PRE_PROBE, step=self.store.step
         ):
-            return self._dropped_step(mb, phi_rows, t0), phi_rows
+            return self._dropped_step(), phi_rows, 0.0
         self.store.ensure_vocab(int(mb.local_vocab.max(initial=0)))
         phi_k = self.store.phi_k.astype(np.float32)                # (K,)
-
-        batch = MinibatchData(
-            word_ids=jnp.asarray(mb.local_word_ids),
-            counts=jnp.asarray(mb.counts),
-        )
         self.key, sub = jax.random.split(self.key)
         refresh = (
             self.shift_detector.consume_refresh()
             if self.shift_detector is not None else False
         )
         num_words = len(phi_rows)
-        padded = pad_vocab_rows(phi_rows)
-        step_fn = self._get_step_fn(
-            (batch.word_ids.shape, padded.shape), refresh=refresh
-        )
-        live_w = max(self.store.live_vocab, self.cfg.W)
-        new_rows, new_phi_k, sweeps, ppl, res_mass = step_fn(
-            sub, batch, jnp.asarray(padded), jnp.asarray(phi_k), live_w,
-            jnp.int32(num_words),
-        )
+        with span("foem.pad_rows"):
+            padded = pad_vocab_rows(phi_rows)
+        with span("foem.stage_in"):
+            host_in = (mb.local_word_ids, mb.counts, padded, phi_k)
+            batch = MinibatchData(
+                word_ids=jnp.asarray(mb.local_word_ids),
+                counts=jnp.asarray(mb.counts),
+            )
+            step_fn = self._get_step_fn(
+                (batch.word_ids.shape, padded.shape), refresh=refresh
+            )
+            live_w = max(self.store.live_vocab, cfg.W)
+            out = step_fn(
+                sub, batch, jnp.asarray(padded), jnp.asarray(phi_k), live_w,
+                jnp.int32(num_words),
+            )
         # One transfer for rows, totals AND the diagnostic scalars: fetching
         # int(sweeps)/float(ppl) separately would stall the prefetch pipeline
         # with two extra device syncs after the row sync.
-        new_rows, new_phi_k, sweeps, ppl, res_mass = jax.device_get(
-            (new_rows, new_phi_k, sweeps, ppl, res_mass)
-        )
+        with span("foem.device_wait") as waited:
+            out = jax.device_get(out)
+        new_rows, new_phi_k, sweeps, ppl, res_mass = out
         new_rows = new_rows[:num_words]         # drop the bucket padding
         new_phi_k = np.asarray(new_phi_k, np.float64)  # lint: host-f64 — RAM accumulator
 
@@ -247,14 +255,14 @@ class FOEMTrainer:
         if self.faults is not None and self.faults.fire(
             fault_lib.POST_FOLD, step=self.store.step
         ):
-            return self._dropped_step(mb, phi_rows, t0), phi_rows
+            return self._dropped_step(), phi_rows, 0.0
 
-        # --- write back + advance cursor ---
-        self.store.write_rows(mb.local_vocab, new_rows)
-        self.store.phi_k = new_phi_k
-        self.store.step += 1
-        if self.checkpoint_every and self.store.step % self.checkpoint_every == 0:
-            self.store.flush()
+        with span("foem.write_back"):
+            self.store.write_rows(mb.local_vocab, new_rows)
+            self.store.phi_k = new_phi_k
+            self.store.step += 1
+            if self.checkpoint_every and self.store.step % self.checkpoint_every == 0:
+                self.store.flush()
 
         # --- lifelong: publish a committed φ snapshot on the cadence ---
         published = -1
@@ -263,17 +271,19 @@ class FOEMTrainer:
             and self.publish_every
             and self.store.step % self.publish_every == 0
         ):
-            published = self.publisher.publish().version
+            with span("foem.publish"):
+                published = self.publisher.publish().version
 
         # --- topic-shift detection over this step's stream signals ---
         events: Tuple = ()
         if self.shift_detector is not None:
-            events = tuple(self.shift_detector.update(
-                step=self.store.step,
-                residual_mass=float(res_mass),
-                perplexity=float(ppl),
-                phi_k=new_phi_k,
-            ))
+            with span("foem.shift"):
+                events = tuple(self.shift_detector.update(
+                    step=self.store.step,
+                    residual_mass=float(res_mass),
+                    perplexity=float(ppl),
+                    phi_k=new_phi_k,
+                ))
 
         base = self._stats_base
         self._stats_base = self.store.bump_pipeline_stats(
@@ -283,7 +293,7 @@ class FOEMTrainer:
             step=self.store.step,
             sweeps=int(sweeps),
             train_ppl=float(ppl),
-            seconds=time.perf_counter() - t0,
+            seconds=0.0,                        # set by _timed
             disk_reads=self._stats_base[0] - base[0],
             disk_writes=self._stats_base[1] - base[1],
             buffer_hits=self._stats_base[2] - base[2],
@@ -292,21 +302,20 @@ class FOEMTrainer:
             residual_mass=float(res_mass),
             published_version=published,
             shift_events=events,
-            scheduler_refresh=refresh,
+            h2d_bytes=sum(a.nbytes for a in host_in),
+            d2h_bytes=sum(np.asarray(a).nbytes for a in out),
         )
         self.history.append(m)
-        return m, new_rows
+        return m, new_rows, waited.seconds
 
-    def _dropped_step(
-        self, mb: Minibatch, phi_rows: np.ndarray, t0: float
-    ) -> StepMetrics:
+    def _dropped_step(self) -> StepMetrics:
         """Account for a minibatch whose contribution a fault discarded.
 
         The store is untouched and the cursor still advances (the stream
         consumed the minibatch); the step index lands in
         ``dropped_steps`` so a driver can re-issue it.  Metrics carry
-        ``sweeps=0`` / ``ppl=nan`` — a visibly-dropped cell, not a fake
-        convergence point.
+        ``sweeps=0`` / ``ppl=nan`` and zero host-stage fields — a
+        visibly-dropped cell, not a fake convergence point.
         """
         self.store.step += 1
         self.dropped_steps.append(self.store.step)
@@ -316,12 +325,25 @@ class FOEMTrainer:
             step=self.store.step,
             sweeps=0,
             train_ppl=float("nan"),
-            seconds=time.perf_counter() - t0,
+            seconds=0.0,                        # set by _timed
             disk_reads=self._stats_base[0] - base[0],
             disk_writes=self._stats_base[1] - base[1],
             buffer_hits=self._stats_base[2] - base[2],
         )
         self.history.append(m)
+        return m
+
+    def _timed(self, m: StepMetrics, seconds: float, device_wait: float,
+               fetch_seconds: float, lock0: float) -> StepMetrics:
+        """Set a step's timings once its ``foem.step`` span has closed.
+        ``lock0`` is the trainer thread's store lock wait when the step
+        began; a dropped step (``sweeps == 0``) keeps its host-stage fields
+        at 0."""
+        m.seconds = seconds
+        if m.sweeps:
+            m.fetch_seconds = fetch_seconds
+            m.lock_wait_seconds = self.store.lock_wait_seconds() - lock0
+            m.host_seconds = seconds - device_wait
         return m
 
     # ------------------------------------------------------------------
@@ -357,6 +379,8 @@ class FOEMTrainer:
         A staged fetch may predate recent write-backs; every write is logged
         with its ``write_version`` and patched into newer-versioned fetches
         before compute — results are bitwise-identical to the sync path.
+        A step's ``foem.step`` span starts before the queue handover, so the
+        step pays its (residual) I/O wait.
         """
         out: List[StepMetrics] = []
         pf = StreamPrefetcher(self.store, stream, depth=self.prefetch_depth)
@@ -366,28 +390,31 @@ class FOEMTrainer:
         it = iter(pf)
         try:
             while max_steps is None or len(out) < max_steps:
-                t0 = time.perf_counter()   # step pays the (residual) I/O wait
-                try:
-                    staged, wait = next(it)
-                except StopIteration:
-                    break
-                mb, rows = staged.minibatch, staged.phi_rows
-                for ver, w_ids, w_rows in writes:
-                    if ver > staged.version:
-                        _, ia, ib = np.intersect1d(
-                            mb.local_vocab, w_ids,
-                            assume_unique=True, return_indices=True,
-                        )
-                        rows[ia] = w_rows[ib]
-                # a hit means the rows were already staged when we arrived
-                # (wait ≈ queue overhead); blocking for the fetch is a miss
-                overlap = max(0.0, staged.fetch_seconds - wait)
-                m, new_rows = self._step_with_rows(
-                    mb, rows,
-                    prefetch_hit=wait < 1e-3,
-                    overlap_seconds=overlap,
-                    t0=t0,
-                )
+                lock0 = self.store.lock_wait_seconds()
+                with span("foem.step") as whole:
+                    with span("foem.wait_staged") as wait:
+                        staged = next(it, None)
+                    if staged is None:
+                        break
+                    mb, rows = staged.minibatch, staged.phi_rows
+                    with span("foem.reconcile"):
+                        for ver, w_ids, w_rows in writes:
+                            if ver > staged.version:
+                                _, ia, ib = np.intersect1d(
+                                    mb.local_vocab, w_ids,
+                                    assume_unique=True, return_indices=True,
+                                )
+                                rows[ia] = w_rows[ib]
+                    # a hit means the rows were already staged when we
+                    # arrived (wait ≈ queue overhead); blocking is a miss
+                    overlap = max(0.0, staged.fetch_seconds - wait.seconds)
+                    m, new_rows, waited = self._step_with_rows(
+                        mb, rows,
+                        prefetch_hit=wait.seconds < 1e-3,
+                        overlap_seconds=overlap,
+                    )
+                self._timed(m, whole.seconds, waited, staged.fetch_seconds,
+                            lock0)
                 writes.append(
                     (self.store.write_version, mb.local_vocab, new_rows)
                 )

@@ -312,24 +312,22 @@ def _serve_loop(rid: int, server: TopicServer, mailbox: _SwapMailbox,
             if plan is not None:
                 plan.fire(fault_lib.REPLICA_KILL, shard=rid, step=n_batches)
             n_batches += 1
-            t0 = time.perf_counter()
             if sim_service_ms > 0.0:
-                time.sleep(sim_service_ms / 1e3)   # device-model service
+                # device-model service: no launch, so a record of its time
+                t0 = time.perf_counter()
+                time.sleep(sim_service_ms / 1e3)
                 theta = np.full((w.shape[0], num_topics),
                                 1.0 / num_topics, np.float32)
-                version = mailbox.version if mailbox.version > 0 else -1
+                rec = {"start": t0,
+                       "launch_seconds": time.perf_counter() - t0,
+                       "cache_hits": 0, "cache_misses": 0,
+                       "version": (mailbox.version if mailbox.version > 0
+                                   else -1)}
             else:
                 with ctx:
-                    theta = server.infer(w, c, key=keys)
-                version = server.last_version
-            secs = time.perf_counter() - t0
-            cache = server.hot_cache
-            cw = cache.window_stats() if cache is not None else None
-            result_q.put((
-                "done", rid, batch_id, np.asarray(theta[:filled]),
-                version, secs,
-                cw.hits if cw else 0, cw.misses if cw else 0,
-            ))
+                    theta, rec = server.launch(w, c, key=keys)
+            result_q.put(("done", rid, batch_id,
+                          np.asarray(theta[:filled]), rec))
         except fault_lib.InjectedFault as e:
             result_q.put(("fault", rid, str(e)))
             return                            # soft replica death
@@ -677,7 +675,8 @@ class ReplicaPool:
                 continue
             kind = msg[0]
             if kind == "done":
-                _, rid, bid, theta, version, secs, ch, cm = msg
+                _, rid, bid, theta, rec = msg
+                version = rec["version"]
                 with self._state_cond:
                     info = self._inflight.pop(bid, None)
                     if info is not None:
@@ -686,15 +685,12 @@ class ReplicaPool:
                 if info is None:
                     continue   # duplicate after a re-issue: drop
                 pub = self._publisher
-                rec = {
-                    "L": info["L"], "filled": info["filled"],
-                    "capacity": self.router.max_batch,
-                    "launch_seconds": secs,
-                    "cache_hits": ch, "cache_misses": cm,
-                    "replica": rid, "version": version,
-                    "published_version": (
+                rec.update(
+                    L=info["L"], filled=info["filled"],
+                    capacity=self.router.max_batch, replica=rid,
+                    published_version=(
                         pub.version if pub is not None else -1),
-                }
+                )
                 self.router.resolve_batch(info["reqs"], theta, version, rec)
             elif kind == "error":
                 _, rid, bid, err = msg

@@ -64,6 +64,7 @@ from repro.data import synthetic_lda_corpus
 from repro.kernels import ops as kops
 from repro.models import build
 from repro.runtime.compile_cache import enable_compile_cache
+from repro.runtime.spans import span
 from repro.sparse.docword import (
     VOCAB_BUCKET,
     DocWordMatrix,
@@ -209,12 +210,12 @@ class TopicServer:
         self.hot_cache = (
             HotRowCache(store, hot_rows) if hot_rows > 0 else None
         )
-        self.last_sweeps = 0                 # fixed-point sweeps of last call
         # --- lifelong publish/subscribe state ---
         self._publisher: Optional[SnapshotPublisher] = None
         self._active: Optional[_ServingVersion] = None   # pinned epoch
         self.swap_log: List[dict] = []       # one record per hot-swap
         self.last_version = -1               # version the last launch used
+        self._launching = threading.local()  # the thread's open launch record
 
     # -------------------------------------------------- lifelong hot-swap
 
@@ -287,50 +288,93 @@ class TopicServer:
 
     def _run(self, word_ids: np.ndarray, counts: np.ndarray,
              ev_counts: Optional[np.ndarray], key: Optional[jax.Array]):
+        """One launch; its stages fill the record :meth:`launch` opened on
+        this thread (a throwaway one outside ``launch``)."""
+        rec = getattr(self._launching, "rec", None)
+        if rec is None:
+            rec = {}
         if key is None:
             key = jax.random.PRNGKey(0)      # deterministic by default
         # pin ONE epoch for the whole launch: rows and phi_k below both come
         # from `active`, so a concurrent refresh() can never tear the batch
         active = self._active
-        uniq, local = localize_vocab(word_ids)
-        rows = self._fetch_rows(uniq, active)              # streamed φ̂
+        with span("serve.localize", rec, "prep_seconds"):
+            uniq, local = localize_vocab(word_ids)
+        with span("serve.gather_rows", rec, "prep_seconds"):
+            rows = self._fetch_rows(uniq, active)          # streamed φ̂
         # pad the local vocab to a bucket boundary so jit traces are reused
         # across requests (padded rows are never indexed by `local`)
-        rows = pad_vocab_rows(rows, self.vocab_pad)
-        args = (
-            key, jnp.asarray(local), jnp.asarray(counts),
-            jnp.asarray(
-                ev_counts if ev_counts is not None
-                else np.zeros_like(counts)
-            ),
-            jnp.asarray(rows),
-            jnp.asarray(
-                active.phi_k if active is not None else self.store.phi_k,
-                jnp.float32,
-            ),
-            self.cfg, self.fit_sweeps, self.check_every, self.rel_tol,
-            self.active_topics, self.use_pallas, self.interpret,
-            self.phi_dtype,
-        )
-        if self.cfg.debug_checks:
-            # functionalize the sanitizer checks through the jitted batch
-            from jax.experimental import checkify
-
-            err, (theta, sweeps, ev_ll) = checkify.checkify(_infer_local)(
-                *args
+        with span("serve.pad_rows", rec, "prep_seconds"):
+            rows = pad_vocab_rows(rows, self.vocab_pad)
+        with span("serve.stage_in", rec, "stage_in_seconds"):
+            host_in = (
+                local, np.asarray(counts),
+                np.asarray(ev_counts if ev_counts is not None
+                           else np.zeros_like(counts)),
+                rows,
+                np.asarray(active.phi_k if active is not None
+                           else self.store.phi_k, np.float32),
             )
-            err.throw()
-        else:
-            theta, sweeps, ev_ll = _infer_local(*args)
-        self.last_sweeps = int(sweeps)
+            rec["h2d_bytes"] = sum(a.nbytes for a in host_in) + (
+                key.nbytes if isinstance(key, np.ndarray) else 0)
+            args = (jnp.asarray(key), *map(jnp.asarray, host_in),
+                    self.cfg, self.fit_sweeps, self.check_every,
+                    self.rel_tol, self.active_topics, self.use_pallas,
+                    self.interpret, self.phi_dtype)
+            if self.cfg.debug_checks:
+                # functionalize the sanitizer checks through the jitted batch
+                from jax.experimental import checkify
+
+                err, (theta, sweeps, ev_ll) = checkify.checkify(
+                    _infer_local)(*args)
+                err.throw()
+            else:
+                theta, sweeps, ev_ll = _infer_local(*args)
+        with span("serve.device_wait", rec, "device_wait_seconds"):
+            rec["sweeps"] = int(sweeps)
+            theta = np.asarray(theta)
         self.last_version = active.version if active is not None else -1
-        return np.asarray(theta), ev_ll
+        return theta, ev_ll
+
+    def launch(self, word_ids: np.ndarray, counts: np.ndarray,
+               key: Optional[jax.Array] = None) -> Tuple[np.ndarray, dict]:
+        """``infer`` plus the launch's record, the entry ``batch_log``
+        keeps: ``start`` and ``launch_seconds`` (``perf_counter`` around
+        the ``infer`` call), ``prep_seconds`` (spans ``serve.localize``,
+        ``serve.gather_rows``, ``serve.pad_rows``), ``stage_in_seconds``
+        (``serve.stage_in``: the copies in and the call),
+        ``device_wait_seconds`` (``serve.device_wait``: the sweep count
+        and θ back), ``sweeps``, ``h2d_bytes`` (host arrays the launch
+        copies in), the hot-row cache's counts since the last launch,
+        ``version`` and ``published_version``.  The launch goes through
+        ``self.infer``, so a wrapper installed on the instance wraps it
+        too."""
+        rec = {"prep_seconds": 0.0, "stage_in_seconds": 0.0,
+               "device_wait_seconds": 0.0, "sweeps": 0, "h2d_bytes": 0}
+        self._launching.rec = rec
+        try:
+            rec["start"] = time.perf_counter()
+            theta = self.infer(word_ids, counts, key=key)
+            rec["launch_seconds"] = time.perf_counter() - rec["start"]
+        finally:
+            self._launching.rec = None
+        cache = self.hot_cache
+        cw = cache.window_stats() if cache is not None else None
+        pub = self._publisher
+        rec.update(
+            cache_hits=cw.hits if cw else 0,
+            cache_misses=cw.misses if cw else 0,
+            # staleness audit trail: the version this launch served vs
+            # the newest committed version at launch time
+            version=self.last_version,
+            published_version=pub.version if pub is not None else -1,
+        )
+        return theta, rec
 
     def infer(self, word_ids: np.ndarray, counts: np.ndarray,
               key: Optional[jax.Array] = None) -> np.ndarray:
         """(B, L) docs -> (B, K) normalized topic mixtures θ (eq. 9)."""
-        theta, _ = self._run(word_ids, counts, None, key)
-        return theta
+        return self._run(word_ids, counts, None, key)[0]
 
     def evaluate(self, word_ids: np.ndarray, est_counts: np.ndarray,
                  ev_counts: np.ndarray,
@@ -347,6 +391,7 @@ class TopicServer:
         self, corpus: DocWordMatrix, doc_ids: Sequence[int],
         batch_size: int, key: Optional[jax.Array] = None,
         bucket_multiple: int = 16,
+        records: Optional[List[dict]] = None,
     ) -> Iterator[Tuple[Sequence[int], np.ndarray]]:
         """Batched/bucketized streaming inference over a request stream.
 
@@ -356,6 +401,8 @@ class TopicServer:
         reused across the stream), derives a per-batch key from ``key``
         (``fold_in`` by batch index — the stream is deterministic end to
         end) and yields ``(chunk_doc_ids, theta (len(chunk), K))``.
+        Each launch's record (:meth:`launch`) is appended to ``records``
+        when given.
         """
         base = jax.random.PRNGKey(0) if key is None else key
         ids = list(doc_ids)
@@ -368,7 +415,9 @@ class TopicServer:
                                                 w.dtype)])
                 c = np.concatenate([c, np.zeros((padding, c.shape[1]),
                                                 c.dtype)])
-            theta = self.infer(w, c, key=jax.random.fold_in(base, i))
+            theta, rec = self.launch(w, c, key=jax.random.fold_in(base, i))
+            if records is not None:
+                records.append(rec)
             yield chunk, theta[: len(chunk)]
 
 
@@ -526,8 +575,11 @@ class AdmissionRouter:
                         timeout=None if deadline is None else deadline - now
                     )
                 stopping = self._stop and not self._pending
-            for item in flush:       # bounded put OUTSIDE the lock:
-                self._queue.put(item)  # backpressure must not stall submit()
+            # bounded put OUTSIDE the lock: backpressure must not stall
+            # submit()
+            for item in flush:
+                with span("serve.flush"):
+                    self._queue.put(item)
             if stopping and not flush:
                 self._queue.put(None)
                 return
@@ -543,11 +595,14 @@ class AdmissionRouter:
     def resolve_batch(self, reqs: Sequence[_Request], thetas,
                       version: int, rec: dict) -> None:
         """Resolve a launched bucket and commit its accounting (batch
-        record + per-request latencies).  Resolutions are counted one by
-        one: if ``set_result`` ever raises mid-loop (e.g. a cancelled
-        future), the already-resolved prefix must still reach
-        ``_resolved`` or ``drain()`` hangs forever on the lost counts."""
+        record + per-request latencies).  The record gains ``queue_wait_s``:
+        per request, its launch's ``start`` minus its submit time.
+        Resolutions are counted one by one: if ``set_result`` ever raises
+        mid-loop (e.g. a cancelled future), the already-resolved prefix
+        must still reach ``_resolved`` or ``drain()`` hangs forever on the
+        lost counts."""
         t1 = time.perf_counter()
+        rec["queue_wait_s"] = [rec["start"] - r.t_submit for r in reqs]
         ok = 0
         try:
             for i, r in enumerate(reqs):
@@ -750,39 +805,26 @@ class ServingEngine:
 
     def _launch_loop(self) -> None:
         while True:
-            item = self.router.next_batch()
+            with span("serve.next_batch"):
+                item = self.router.next_batch()
             if item is None:
                 return
             L, reqs = item
-            try:
-                # hot-swap point: the launcher is the only thread that
-                # launches, so swapping BETWEEN launches gives zero
-                # downtime — no launch ever straddles two versions
-                self.server.refresh()
-                self._launch(L, reqs)
-            except BaseException as e:   # resolve, never hang the callers
-                self.router.fail_batch(reqs, e)
+            with span("serve.launch"):
+                try:
+                    # hot-swap point: the launcher is the only thread that
+                    # launches, so swapping BETWEEN launches gives zero
+                    # downtime — no launch ever straddles two versions
+                    self.server.refresh()
+                    self._launch(L, reqs)
+                except BaseException as e:  # resolve, never hang the callers
+                    self.router.fail_batch(reqs, e)
 
     def _launch(self, L: int, reqs: List[_Request]) -> None:
         w, c, keys = pad_batch(L, reqs, self.max_batch)
-        t0 = time.perf_counter()
-        theta = self.server.infer(w, c, key=jnp.asarray(keys))
-        t1 = time.perf_counter()
-        version = self.server.last_version
-        pub = self.server._publisher
-        cache = self.server.hot_cache
-        cw = cache.window_stats() if cache is not None else None
-        rec = {
-            "L": L, "filled": len(reqs), "capacity": self.max_batch,
-            "launch_seconds": t1 - t0,
-            "cache_hits": cw.hits if cw else 0,
-            "cache_misses": cw.misses if cw else 0,
-            # staleness audit trail: the version this launch served vs the
-            # newest committed version at launch time
-            "version": version,
-            "published_version": pub.version if pub is not None else -1,
-        }
-        self.router.resolve_batch(reqs, theta, version, rec)
+        theta, rec = self.server.launch(w, c, key=jnp.asarray(keys))
+        rec.update(L=L, filled=len(reqs), capacity=self.max_batch)
+        self.router.resolve_batch(reqs, theta, rec["version"], rec)
 
     # -------------------------------------------------------------- plumbing
 
@@ -988,8 +1030,10 @@ def serve_lda(args) -> None:
     corpus, _ = synthetic_lda_corpus(args.requests, args.vocab,
                                      args.topics, seed=123)
     ids = list(range(corpus.num_docs))
+    records: List[dict] = []
     t0 = time.time()
-    for chunk, theta in server.infer_stream(corpus, ids, args.batch):
+    for chunk, theta in server.infer_stream(corpus, ids, args.batch,
+                                            records=records):
         top = np.argsort(-theta, axis=1)[:, :3]
         if chunk[0] == ids[0]:
             for d in range(min(4, len(chunk))):
@@ -1000,7 +1044,7 @@ def serve_lda(args) -> None:
     dt = time.time() - t0
     print(f"served {len(ids)} docs in {dt:.2f}s "
           f"({len(ids)/dt:.1f} docs/s, batch={args.batch}, "
-          f"{server.last_sweeps} fixed-point sweeps on the last batch)")
+          f"{records[-1]['sweeps']} fixed-point sweeps on the last batch)")
 
 
 def serve_lm(args) -> None:

@@ -74,7 +74,7 @@ def test_stream_prefetcher_reconciliation_token(tmp_path):
 
     pf = StreamPrefetcher(store, [_MB([1, 2, 3])], depth=1)
     try:
-        (staged, _wait), = list(pf)
+        (staged,) = list(pf)
     finally:
         pf.close()
     v_after = store.write_rows(np.array([2]), np.ones((1, 4), np.float32))
