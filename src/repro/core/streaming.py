@@ -45,6 +45,11 @@ a byte budget), ``W_s`` is the per-minibatch unique vocabulary, and
 ``prefetch_depth`` is the number of minibatches fetched ahead (1 = double
 buffering, the Fig. 4 "while GPU computes, CPU fetches" overlap).
 
+Where the whole φ̂ and the step fit on the device, the trainer keeps φ̂ there
+instead (the store's device row tier): rows are gathered and scattered on
+the device, and this host path becomes the overflow and persistence tier,
+written back only when someone reads the store.
+
 At pod scale the same role is played by sharding φ̂ over the ``model`` mesh
 axis (see ``parallel/sharding.py``); this module is the single-host tier and
 the checkpoint substrate.
@@ -52,6 +57,7 @@ the checkpoint substrate.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import io
 import json
 import os
@@ -61,10 +67,13 @@ import time
 import zlib
 from typing import Iterable, Iterator, List, NamedTuple, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.runtime import faults as fault_lib
 from repro.runtime.spans import span
+from repro.sparse.docword import VOCAB_BUCKET
 
 
 class StoreCorruptionError(RuntimeError):
@@ -171,6 +180,42 @@ class _TimedLock:
         return getattr(self._waits, "seconds", 0.0)
 
 
+#: Rows one device-tier transfer moves at most (a multiple of the W_s
+#: bucket): an upload or a whole-table sync holds one such block on the host.
+TIER_CHUNK = 16 * VOCAB_BUCKET
+#: The device tier's rows are padded to a multiple of the TPU's 128 lanes:
+#: a row gather or scatter on a table whose minor dimension is not lane
+#: aligned copies the whole table first (6 GB of temporaries at PubMed's
+#: K = 10^4, by the TPU compiler's memory analysis).
+TIER_LANES = 128
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _tier_gather(table, ids, k):
+    """Rows ``ids`` of the device tier, first ``k`` lanes; an id past the
+    table reads zeros."""
+    return table.at[ids].get(mode="fill", fill_value=0)[:, :k]
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _tier_scatter(table, ids, rows):
+    """The tier with rows ``ids`` replaced (``rows`` zero-padded to the
+    lanes); an id past the table is dropped.  Whole rows: the TPU compiler
+    turns a scatter into part of each row into a loop over the ids."""
+    pad = table.shape[1] - rows.shape[1]
+    return table.at[ids].set(jnp.pad(rows, ((0, 0), (0, pad))), mode="drop")
+
+
+def tier_ids(word_ids: np.ndarray, capacity: int) -> np.ndarray:
+    """``word_ids`` as int32, padded up to the W_s bucket with ``capacity``
+    — an id past the table, which the tier's gather reads as a zero row and
+    its scatter drops (the padding of ``pad_vocab_rows``)."""
+    n = len(word_ids)
+    out = np.full((-(-n // VOCAB_BUCKET) * VOCAB_BUCKET,), capacity, np.int32)
+    out[:n] = word_ids
+    return out
+
+
 class ParameterStore:
     """Disk-backed φ̂_{W×K} with a write-back LRU hot-word buffer.
 
@@ -191,6 +236,15 @@ class ParameterStore:
 
     Row ids within one ``fetch_rows``/``write_rows`` call must be unique —
     they are a minibatch's (deduplicated) local vocabulary.
+
+    Device row tier: ``attach_tier`` puts the whole ``(capacity, K)`` table
+    on a device, where the trainer gathers and scatters its steps' rows
+    (``tier_gather``/``tier_write``); the host buffer and memmap become the
+    overflow and persistence tier.  Rows the tier holds newer than the host
+    copy are dirty, and every host read writes back the dirty rows it covers
+    first (``fetch_rows``, ``flush`` and so ``dense_phi`` and
+    ``SnapshotPublisher.publish``; span ``store.sync``), so readers on any
+    thread see what the tier holds.  ``detach_tier`` writes back the rest.
 
     Parameters
     ----------
@@ -254,6 +308,9 @@ class ParameterStore:
         self._buf_dirty = np.zeros((W_star,), bool)
         self._slot_of = np.full((self.capacity,), -1, np.int64)
         self._clock = 0
+        # ---- device row tier (None: detached) ----
+        self._tier = None
+        self._tier_dirty = np.zeros((self.capacity,), bool)
         backing = os.path.join(path, self.BACKING)
         if self.readonly:
             if not os.path.exists(backing):
@@ -277,6 +334,8 @@ class ParameterStore:
         # skip np.memmap.__getitem__'s subclass overhead (~4x on 4096-row
         # blocks); durability still goes through self._mm.flush().
         self._arr = np.asarray(self._mm)
+        # a new backing file holds zeros until the first row write
+        self._pristine = mode == "w+"
         if mode == "r+":
             self._recover()
 
@@ -375,6 +434,7 @@ class ParameterStore:
                     f"{self.capacity}; grow capacity at construction "
                     "(static allocation for XLA)"
                 )
+            self._sync_tier(ids)
             if self.buffer_rows == 0:
                 out = self._read_backing(ids)
                 self.stats.disk_reads += len(ids)
@@ -418,14 +478,119 @@ class ParameterStore:
             ids = np.asarray(word_ids, np.int64)
             rows = np.asarray(rows, self.dtype)
             self._changed[ids] = True
-            if self.buffer_rows > 0:
-                self._insert(ids, rows, dirty=True)
-            else:
-                order = np.argsort(ids)           # sorted scatter: sequential I/O
-                self._arr[ids[order]] = rows[order]
-                self.stats.disk_writes += len(ids)
+            self._put_rows(ids, rows)
+            if self._tier is not None:            # the tier holds them too
+                self._tier = self._upload(self._tier, ids, rows)
+                self._tier_dirty[ids] = False
             self.write_version += 1
             return self.write_version
+
+    def _put_rows(self, ids: np.ndarray, rows: np.ndarray) -> None:
+        """Host-tier write: into the hot buffer (dirty), or a sorted
+        scatter into the backing store when unbuffered."""
+        self._pristine = False
+        if self.buffer_rows > 0:
+            self._insert(ids, rows, dirty=True)
+        else:
+            order = np.argsort(ids)           # sorted scatter: sequential I/O
+            self._arr[ids[order]] = rows[order]
+            self.stats.disk_writes += len(ids)
+
+    def _read_rows(self, ids: np.ndarray) -> np.ndarray:
+        """Host-tier read with no promotion and no I/O accounting."""
+        rows = np.array(self._read_backing(ids))
+        if self.buffer_rows > 0:
+            slots = self._slot_of[ids]
+            hit = slots >= 0
+            rows[hit] = self._buf[slots[hit]]
+        return rows
+
+    # ---------------------------------------------------- device row tier
+
+    @property
+    def has_tier(self) -> bool:
+        return self._tier is not None
+
+    def tier_bytes(self) -> int:
+        """Device bytes of the tier: the whole table, K lane-padded."""
+        return self.capacity * self._tier_lanes() * self.dtype.itemsize
+
+    def _tier_lanes(self) -> int:
+        return -(-self.K // TIER_LANES) * TIER_LANES
+
+    def attach_tier(self) -> int:
+        """Hold the whole table on the default device (its rows padded to
+        ``TIER_LANES``), filled from the host tier (a store never written is
+        allocated as zeros there, with no copy).  Returns the rows uploaded.
+
+        The tier's arrays are not committed to the device, so the rows it
+        gathers reach the step program as the streamed path's rows do: one
+        compiled program serves both."""
+        self._check_writable()
+        with self._lock:
+            if self._tier is not None:
+                return 0
+            table = jnp.zeros((self.capacity, self._tier_lanes()), self.dtype)
+            uploaded = 0
+            if not self._pristine:
+                for lo in range(0, self.capacity, TIER_CHUNK):
+                    ids = np.arange(lo, min(lo + TIER_CHUNK, self.capacity))
+                    table = self._upload(table, ids, self._read_rows(ids))
+                uploaded = self.capacity
+            self._tier = table
+            self._tier_dirty[:] = False
+            return uploaded
+
+    def detach_tier(self) -> None:
+        """Write the tier's dirty rows back to the host tier and free it."""
+        with self._lock:
+            if self._tier is not None:
+                self._sync_tier()
+                self._tier = None
+
+    def tier_gather(self, ids: jax.Array) -> jax.Array:
+        """The rows ``ids`` (from :func:`tier_ids`) on the device; padding
+        rows are zeros."""
+        with self._lock:
+            return _tier_gather(self._tier, ids, self.K)
+
+    def tier_write(self, word_ids: np.ndarray, ids: jax.Array,
+                   rows: jax.Array) -> int:
+        """A step's write-back into the tier: rows ``ids`` (from
+        :func:`tier_ids` of ``word_ids``) replaced by ``rows``, in place.
+        Like ``write_rows`` it marks the rows changed and returns the new
+        ``write_version``; the host copy stays behind until a read."""
+        with self._lock:
+            self._tier = _tier_scatter(self._tier, ids, rows)
+            self._tier_dirty[word_ids] = True
+            self._changed[word_ids] = True
+            self.write_version += 1
+            return self.write_version
+
+    def _upload(self, table, ids: np.ndarray, rows: np.ndarray):
+        """``table`` with rows ``ids`` set from host ``rows``."""
+        pad = tier_ids(ids, self.capacity)
+        block = np.zeros((len(pad), self.K), self.dtype)
+        block[: len(ids)] = rows
+        return _tier_scatter(table, jnp.asarray(pad), jnp.asarray(block))
+
+    def _sync_tier(self, ids: Optional[np.ndarray] = None) -> None:
+        """Write back the tier's dirty rows among ``ids`` (all when None)
+        to the host tier.  The caller holds ``_lock``."""
+        if self._tier is None:
+            return
+        dirty = self._tier_dirty
+        which = np.flatnonzero(dirty) if ids is None else ids[dirty[ids]]
+        if not len(which):
+            return
+        with span("store.sync"):
+            for lo in range(0, len(which), TIER_CHUNK):
+                part = which[lo: lo + TIER_CHUNK]
+                rows = _tier_gather(
+                    self._tier, jnp.asarray(tier_ids(part, self.capacity)),
+                    self.K)
+                self._put_rows(part, np.asarray(rows)[: len(part)])
+            dirty[which] = False
 
     # ----------------------------------------------------- LRU internals
 
@@ -549,6 +714,7 @@ class ParameterStore:
         """
         self._check_writable()
         with self._lock:
+            self._sync_tier()
             dirty_slots = np.flatnonzero(self._buf_dirty)
             d_ids = self._buf_ids[dirty_slots]
             order = np.argsort(d_ids)
@@ -1134,12 +1300,13 @@ class HotRowCache:
 
 
 class PrefetchedBatch(NamedTuple):
-    """A minibatch staged by the worker: its φ̂ rows, the store version the
-    fetch is consistent with, and how long the host I/O took (span
-    ``foem.fetch``, any store lock wait included)."""
+    """A minibatch staged by the worker: its φ̂ rows (None while the store
+    has a device tier, which holds them), the store version the fetch is
+    consistent with, and how long the host I/O took (span ``foem.fetch``,
+    any store lock wait included)."""
 
     minibatch: object            # sparse.minibatch.Minibatch
-    phi_rows: np.ndarray         # (W_s, K)
+    phi_rows: Optional[np.ndarray]  # (W_s, K)
     version: int                 # store.write_version at fetch time
     fetch_seconds: float
 
@@ -1161,7 +1328,8 @@ class StreamPrefetcher:
     makes prefetched and sequential execution bitwise-identical.
 
     The worker's stages are spans ``foem.next_minibatch`` (the stream's
-    bucketize and localize) and ``foem.fetch``.
+    bucketize and localize) and ``foem.fetch``.  While the store has a
+    device tier the worker fetches no rows: the trainer gathers them there.
     """
 
     def __init__(self, store: ParameterStore, stream: Iterable, depth: int = 1):
@@ -1179,7 +1347,11 @@ class StreamPrefetcher:
                 if mb is None:
                     return
                 with span("foem.fetch") as fetch:
-                    rows, version = store.fetch_rows_versioned(mb.local_vocab)
+                    if store.has_tier:
+                        rows, version = None, store.write_version
+                    else:
+                        rows, version = store.fetch_rows_versioned(
+                            mb.local_vocab)
                 yield PrefetchedBatch(mb, rows, version, fetch.seconds)
 
         self._inner = prefetch_iterator(staged(), depth=depth)

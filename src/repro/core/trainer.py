@@ -14,8 +14,14 @@ reconciled against in-flight fetches (see ``streaming.StreamPrefetcher``) —
 the pipelined step costs ≈ max(device compute, host I/O) instead of their
 sum, with bitwise-identical results.
 
-The device never holds more than O(K·(D_s + NNZ_s + W_s)) — the paper's
-space bound with W* = buffer_rows.
+Two memory regimes, one algorithm.  Where the whole φ̂ table fits on the
+device beside the compiled step (``device_tier_fits``), the store holds it
+there as a device row tier: stage 2 is a gather on the device, stage 4 a
+scatter, and no row crosses to the host until someone reads the store.  The
+device then holds W_cap·K for φ̂ plus the step's O(K·(D_s + NNZ_s + W_s)).
+Otherwise rows stream from the host store, and the device never holds more
+than O(K·(D_s + NNZ_s + W_s)) — the paper's space bound with W* =
+buffer_rows.  Both regimes give bitwise-identical results.
 """
 from __future__ import annotations
 
@@ -28,12 +34,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import em, foem, sem
-from repro.core.streaming import ParameterStore, StreamPrefetcher
+from repro.core.streaming import ParameterStore, StreamPrefetcher, tier_ids
 from repro.core.types import GlobalStats, LDAConfig, MinibatchData
 from repro.runtime import faults as fault_lib
 from repro.runtime.spans import span
-from repro.sparse.docword import pad_vocab_rows
+from repro.sparse.docword import VOCAB_BUCKET, pad_vocab_rows
 from repro.sparse.minibatch import Minibatch, MinibatchStream
+
+#: Share of the device's memory the fit rule leaves free beside the tier and
+#: the compiled step: the tier's gather and scatter, the allocator's
+#: fragmentation and whatever else the process keeps on the device.
+TIER_MARGIN = 1 / 16
+
+
+def device_tier_fits(device, table_bytes: int,
+                     step_bytes: Callable[[], int]) -> bool:
+    """The fit rule of the device row tier: the table's bytes, the compiled
+    step's (``step_bytes()``, read only when there is a limit) and a margin
+    of ``TIER_MARGIN`` fit in the device's ``bytes_limit``.  A device that
+    states no limit (the CPU backend) fits."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return True
+    return table_bytes + step_bytes() + TIER_MARGIN * limit <= limit
 
 
 @dataclasses.dataclass
@@ -58,6 +81,9 @@ class StepMetrics:
     host_seconds: float = 0.0       # foem.step minus foem.device_wait
     h2d_bytes: int = 0              # host arrays passed into the step program
     d2h_bytes: int = 0              # arrays device_get returned
+    rows: int = 0                   # the step's φ̂ rows (W_s)
+    tier_rows: int = 0              # of them, served from the device tier
+    tier_uploads: int = 0           # rows uploaded to the tier for this step
 
 
 class FOEMTrainer:
@@ -104,6 +130,15 @@ class FOEMTrainer:
         # the sublane tile, so the sweep kernels are eligible) and a
         # stream of varying W_s compiles once per bucket
         self._jit_cache: Dict = {}
+        # device row tier: the fit rule's answer per step shape; once it
+        # says no, the trainer streams rows for good (no attach/detach
+        # thrash).  Staged fetches older than _fetch_floor predate a
+        # detach and miss the tier's writes: they are fetched again.
+        self._device = jax.devices()[0]
+        self._tier_fit: Dict = {}
+        self._tier_off = store.readonly
+        self._tier_uploads = 0
+        self._fetch_floor = 0
 
     # ------------------------------------------------------------------
 
@@ -158,6 +193,7 @@ class FOEMTrainer:
             err.throw()
             return out
 
+        run_checked.jitted = fn      # what the fit rule reads the memory of
         return run_checked
 
     def _get_step_fn(self, shapes, refresh: bool = False):
@@ -182,27 +218,79 @@ class FOEMTrainer:
 
     # ------------------------------------------------------------------
 
+    def _shapes(self, mb: Minibatch):
+        """The step program's cache key: (D_s, L) and the padded rows."""
+        w_pad = -(-len(mb.local_vocab) // VOCAB_BUCKET) * VOCAB_BUCKET
+        return (mb.local_word_ids.shape, (w_pad, self.cfg.K))
+
+    def _step_bytes(self, mb: Minibatch, shapes) -> int:
+        """Device bytes of the compiled step for ``shapes``: arguments +
+        outputs + temporaries − donated, from the jitted program that
+        ``_local_step_fn`` built (a planted fault may wrap what
+        ``_get_step_fn`` returns).  The call that follows reuses this
+        compile."""
+        self._get_step_fn(shapes)
+        fn = self._jit_cache[(self.algorithm, shapes, False)]
+        fn = getattr(fn, "jitted", fn)
+        batch = MinibatchData(word_ids=jnp.asarray(mb.local_word_ids),
+                              counts=jnp.asarray(mb.counts))
+        mem = fn.lower(
+            self.key, batch, jax.ShapeDtypeStruct(shapes[1], self.store.dtype),
+            jax.ShapeDtypeStruct((self.cfg.K,), jnp.float32),
+            max(self.store.live_vocab, self.cfg.W), jnp.int32(0),
+        ).compile().memory_analysis()
+        return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+    def _use_tier(self, mb: Minibatch) -> bool:
+        """Whether this step's rows live in the store's device tier.
+
+        The fit rule answers once per step shape.  A yes attaches the tier
+        (if it is not); the first no detaches it, writing its dirty rows
+        back, and the trainer streams rows from then on.
+        """
+        if self._tier_off:
+            return False
+        shapes = self._shapes(mb)
+        fits = self._tier_fit.get(shapes)
+        if fits is None:
+            fits = self._tier_fit[shapes] = device_tier_fits(
+                self._device, self.store.tier_bytes(),
+                lambda: self._step_bytes(mb, shapes))
+        if not fits:
+            self._tier_off = True
+            self.store.detach_tier()
+            self._fetch_floor = self.store.write_version
+            return False
+        if not self.store.has_tier:
+            self._tier_uploads += self.store.attach_tier()
+        return True
+
     def step(self, mb: Minibatch) -> StepMetrics:
         """Synchronous step: fetch → compute → write back."""
         lock0 = self.store.lock_wait_seconds()
         with span("foem.step") as whole:
+            tier = self._use_tier(mb)
             with span("foem.fetch") as fetch:
-                phi_rows = self.store.fetch_rows(mb.local_vocab)   # (W_s, K)
+                phi_rows = (None if tier                       # (W_s, K)
+                            else self.store.fetch_rows(mb.local_vocab))
             m, _, waited = self._step_with_rows(mb, phi_rows)
         return self._timed(m, whole.seconds, waited, fetch.seconds, lock0)
 
     def _step_with_rows(
         self,
         mb: Minibatch,
-        phi_rows: np.ndarray,
+        phi_rows: Optional[np.ndarray],
         *,
         prefetch_hit: bool = False,
         overlap_seconds: float = 0.0,
-    ) -> Tuple[StepMetrics, np.ndarray, float]:
-        """Run the jitted inner loop on pre-fetched rows and write back.
+    ) -> Tuple[StepMetrics, Optional[np.ndarray], float]:
+        """Run the jitted inner loop on pre-fetched rows and write back;
+        ``phi_rows=None`` gathers and scatters them in the device tier.
 
         Returns ``(metrics, new_rows, device_wait_seconds)`` — new_rows
-        feed the prefetch reconciliation log.  It runs inside the caller's
+        (None on the tier) feed the prefetch reconciliation log.  It runs
+        inside the caller's
         ``foem.step`` span, which sets the timings with :meth:`_timed`.
         I/O counters are per-step deltas of the store's cumulative stats;
         in the pipelined path a step's delta includes the *next*
@@ -223,30 +311,43 @@ class FOEMTrainer:
             self.shift_detector.consume_refresh()
             if self.shift_detector is not None else False
         )
-        num_words = len(phi_rows)
-        with span("foem.pad_rows"):
-            padded = pad_vocab_rows(phi_rows)
+        tier = phi_rows is None
+        num_words = len(mb.local_vocab)
+        if tier:
+            ids = tier_ids(mb.local_vocab, self.store.capacity)
+        else:
+            with span("foem.pad_rows"):
+                padded = pad_vocab_rows(phi_rows)
         with span("foem.stage_in"):
-            host_in = (mb.local_word_ids, mb.counts, padded, phi_k)
+            host_in = (mb.local_word_ids, mb.counts,
+                       ids if tier else padded, phi_k)
             batch = MinibatchData(
                 word_ids=jnp.asarray(mb.local_word_ids),
                 counts=jnp.asarray(mb.counts),
             )
-            step_fn = self._get_step_fn(
-                (batch.word_ids.shape, padded.shape), refresh=refresh
-            )
+            if tier:
+                ids = jnp.asarray(ids)
+                rows_in = self.store.tier_gather(ids)
+            else:
+                rows_in = jnp.asarray(padded)
+            step_fn = self._get_step_fn(self._shapes(mb), refresh=refresh)
             live_w = max(self.store.live_vocab, cfg.W)
             out = step_fn(
-                sub, batch, jnp.asarray(padded), jnp.asarray(phi_k), live_w,
+                sub, batch, rows_in, jnp.asarray(phi_k), live_w,
                 jnp.int32(num_words),
             )
         # One transfer for rows, totals AND the diagnostic scalars: fetching
         # int(sweeps)/float(ppl) separately would stall the prefetch pipeline
-        # with two extra device syncs after the row sync.
+        # with two extra device syncs after the row sync.  On the tier the
+        # rows stay on the device.
         with span("foem.device_wait") as waited:
-            out = jax.device_get(out)
-        new_rows, new_phi_k, sweeps, ppl, res_mass = out
-        new_rows = new_rows[:num_words]         # drop the bucket padding
+            fetched = jax.device_get(out[1:] if tier else out)
+        if tier:
+            new_rows = out[0]
+            new_phi_k, sweeps, ppl, res_mass = fetched
+        else:
+            new_rows, new_phi_k, sweeps, ppl, res_mass = fetched
+            new_rows = new_rows[:num_words]     # drop the bucket padding
         new_phi_k = np.asarray(new_phi_k, np.float64)  # lint: host-f64 — RAM accumulator
 
         # post-fold: the local fold is complete but unpublished — a "kill"
@@ -258,7 +359,11 @@ class FOEMTrainer:
             return self._dropped_step(), phi_rows, 0.0
 
         with span("foem.write_back"):
-            self.store.write_rows(mb.local_vocab, new_rows)
+            if tier:
+                self.store.tier_write(mb.local_vocab, ids, new_rows)
+                new_rows = None
+            else:
+                self.store.write_rows(mb.local_vocab, new_rows)
             self.store.phi_k = new_phi_k
             self.store.step += 1
             if self.checkpoint_every and self.store.step % self.checkpoint_every == 0:
@@ -303,8 +408,12 @@ class FOEMTrainer:
             published_version=published,
             shift_events=events,
             h2d_bytes=sum(a.nbytes for a in host_in),
-            d2h_bytes=sum(np.asarray(a).nbytes for a in out),
+            d2h_bytes=sum(np.asarray(a).nbytes for a in fetched),
+            rows=num_words,
         )
+        if tier:
+            m.tier_rows, m.tier_uploads = num_words, self._tier_uploads
+            self._tier_uploads = 0
         self.history.append(m)
         return m, new_rows, waited.seconds
 
@@ -354,18 +463,24 @@ class FOEMTrainer:
         max_steps: Optional[int] = None,
         callback: Optional[Callable[[StepMetrics], None]] = None,
     ) -> List[StepMetrics]:
-        if self.prefetch_depth > 0:
-            return self._fit_stream_prefetched(stream, max_steps, callback)
-        out = []
-        for mb in stream:
-            if max_steps is not None and len(out) >= max_steps:
-                break
-            m = self.step(mb)
-            out.append(m)
-            if callback:
-                callback(m)
-        self.store.flush()
-        return out
+        """Train on ``stream``; the store is flushed at its end.  The call
+        leaves φ̂ in the host store: it detaches the device tier when it
+        returns or raises."""
+        try:
+            if self.prefetch_depth > 0:
+                return self._fit_stream_prefetched(stream, max_steps, callback)
+            out = []
+            for mb in stream:
+                if max_steps is not None and len(out) >= max_steps:
+                    break
+                m = self.step(mb)
+                out.append(m)
+                if callback:
+                    callback(m)
+            self.store.flush()
+            return out
+        finally:
+            self.store.detach_tier()
 
     def _fit_stream_prefetched(
         self,
@@ -379,8 +494,11 @@ class FOEMTrainer:
         A staged fetch may predate recent write-backs; every write is logged
         with its ``write_version`` and patched into newer-versioned fetches
         before compute — results are bitwise-identical to the sync path.
-        A step's ``foem.step`` span starts before the queue handover, so the
-        step pays its (residual) I/O wait.
+        On the device tier the worker stages no rows and nothing is
+        reconciled; a batch staged without rows, or before a detach, is
+        fetched again on the trainer thread.  A step's ``foem.step`` span
+        starts before the queue handover, so the step pays its (residual)
+        I/O wait.
         """
         out: List[StepMetrics] = []
         pf = StreamPrefetcher(self.store, stream, depth=self.prefetch_depth)
@@ -397,14 +515,24 @@ class FOEMTrainer:
                     if staged is None:
                         break
                     mb, rows = staged.minibatch, staged.phi_rows
-                    with span("foem.reconcile"):
-                        for ver, w_ids, w_rows in writes:
-                            if ver > staged.version:
-                                _, ia, ib = np.intersect1d(
-                                    mb.local_vocab, w_ids,
-                                    assume_unique=True, return_indices=True,
-                                )
-                                rows[ia] = w_rows[ib]
+                    fetch_seconds = staged.fetch_seconds
+                    if self._use_tier(mb):
+                        rows = None
+                    elif rows is None or staged.version < self._fetch_floor:
+                        # staged while the tier held the rows
+                        with span("foem.fetch") as fetch:
+                            rows = self.store.fetch_rows(mb.local_vocab)
+                        fetch_seconds += fetch.seconds
+                    else:
+                        with span("foem.reconcile"):
+                            for ver, w_ids, w_rows in writes:
+                                if ver > staged.version:
+                                    _, ia, ib = np.intersect1d(
+                                        mb.local_vocab, w_ids,
+                                        assume_unique=True,
+                                        return_indices=True,
+                                    )
+                                    rows[ia] = w_rows[ib]
                     # a hit means the rows were already staged when we
                     # arrived (wait ≈ queue overhead); blocking is a miss
                     overlap = max(0.0, staged.fetch_seconds - wait.seconds)
@@ -413,11 +541,11 @@ class FOEMTrainer:
                         prefetch_hit=wait.seconds < 1e-3,
                         overlap_seconds=overlap,
                     )
-                self._timed(m, whole.seconds, waited, staged.fetch_seconds,
-                            lock0)
-                writes.append(
-                    (self.store.write_version, mb.local_vocab, new_rows)
-                )
+                self._timed(m, whole.seconds, waited, fetch_seconds, lock0)
+                if new_rows is not None:
+                    writes.append(
+                        (self.store.write_version, mb.local_vocab, new_rows)
+                    )
                 out.append(m)
                 if callback:
                     callback(m)

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 import pytest
 
+import repro.core.trainer as trainer_mod
 from repro.core import FOEMTrainer, LDAConfig, ParameterStore
 from repro.data import synthetic_lda_corpus
 from repro.launch.replica import ReplicaPool
@@ -39,6 +40,12 @@ def _stream():
     return MinibatchStream(corpus, D, seed=0, epochs=None)
 
 
+@pytest.fixture
+def streamed(monkeypatch):
+    """Rows streamed from the host store: the fit rule says no."""
+    monkeypatch.setattr(trainer_mod, "device_tier_fits", lambda *a: False)
+
+
 def test_span_adds_to_record_and_keeps_seconds():
     rec = {}
     with span("test.stage", rec, "t") as a:
@@ -52,11 +59,7 @@ def test_span_adds_to_record_and_keeps_seconds():
     assert c.seconds >= 0.0
 
 
-@pytest.mark.parametrize("depth", [0, 1])
-def test_step_metrics_host_fields(tmp_path, depth):
-    """Both training paths fill the host-stage fields; the bytes are what
-    the shapes give."""
-    tr = _trainer(tmp_path, depth)
+def _three_steps(tr):
     stream = _stream()
     mbs = []
 
@@ -67,7 +70,14 @@ def test_step_metrics_host_fields(tmp_path, depth):
 
     ms = tr.fit_stream(feed(), max_steps=3)
     assert len(ms) == 3
-    for m, mb in zip(ms, mbs):
+    return zip(ms, mbs)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_step_metrics_host_fields(tmp_path, depth, streamed):
+    """Both training paths fill the host-stage fields; the bytes are what
+    the shapes give."""
+    for m, mb in _three_steps(_trainer(tmp_path, depth)):
         w_pad = -(-len(mb.local_vocab) // VOCAB_BUCKET) * VOCAB_BUCKET
         L = mb.word_ids.shape[1]
         assert m.h2d_bytes == D * L * 4 + D * L * 4 + w_pad * K * 4 + K * 4
@@ -75,6 +85,23 @@ def test_step_metrics_host_fields(tmp_path, depth):
         assert m.fetch_seconds > 0.0
         assert 0.0 < m.host_seconds < m.seconds
         assert m.lock_wait_seconds >= 0.0
+        assert m.tier_rows == 0 and m.rows == len(mb.local_vocab)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_step_metrics_tier_fields(tmp_path, depth):
+    """On the device tier no row crosses: ids, counts, the padded row ids
+    and the totals go in, the totals and three scalars come back, and every
+    row is served from the tier."""
+    for m, mb in _three_steps(_trainer(tmp_path, depth)):
+        w_pad = -(-len(mb.local_vocab) // VOCAB_BUCKET) * VOCAB_BUCKET
+        L = mb.word_ids.shape[1]
+        assert m.h2d_bytes == D * L * 4 + D * L * 4 + w_pad * 4 + K * 4
+        assert m.d2h_bytes == K * 4 + 3 * 4
+        assert m.tier_rows == m.rows == len(mb.local_vocab)
+        assert m.tier_uploads == 0
+        assert m.fetch_seconds >= 0.0
+        assert 0.0 < m.host_seconds < m.seconds
 
 
 def test_dropped_step_reads_zero(tmp_path):
@@ -137,6 +164,8 @@ def _inside(child, parents):
 
 TRAIN_CHILDREN = ["foem.wait_staged", "foem.reconcile", "foem.pad_rows",
                   "foem.stage_in", "foem.device_wait", "foem.write_back"]
+TIER_CHILDREN = ["foem.wait_staged", "foem.stage_in", "foem.device_wait",
+                 "foem.write_back"]
 WORKER_SPANS = ["foem.next_minibatch", "foem.fetch"]
 SERVE_CHILDREN = ["serve.localize", "serve.gather_rows", "serve.pad_rows",
                   "serve.stage_in", "serve.device_wait"]
@@ -146,7 +175,9 @@ def _by_name(events, names):
     return {n: [e for e in events if e[0] == n] for n in names}
 
 
-def test_training_spans_in_the_profile(tmp_path):
+def _traced_steps(tmp_path, children):
+    """Two traced steps: each stage of ``children`` once a step, inside it
+    and in the order listed; the worker's spans on a line of their own."""
     tr = _trainer(tmp_path, 1)
     tr.fit_stream(iter(_stream()), max_steps=1)          # compile first
     log_dir = str(tmp_path / "trace")
@@ -157,19 +188,33 @@ def test_training_spans_in_the_profile(tmp_path):
                      if any(e[0] == "foem.step" for e in evs))
     steps = [e for e in step_line if e[0] == "foem.step"]
     assert len(steps) == 2
-    kids = _by_name(step_line, TRAIN_CHILDREN)
-    for name in TRAIN_CHILDREN:          # each stage once a step, inside it
+    kids = _by_name(step_line, children)
+    for name in children:                # each stage once a step, inside it
         assert len(kids[name]) == 2, name
         assert all(_inside(e, steps) for e in kids[name]), name
     for st in steps:                     # in the order listed
         starts = [next(e[1] for e in kids[n] if _inside(e, [st]))
-                  for n in TRAIN_CHILDREN]
+                  for n in children]
         assert starts == sorted(starts)
     worker = next(evs for evs in lines.values()
                   if any(e[0] == "foem.fetch" for e in evs))
     assert worker is not step_line
     for name in WORKER_SPANS:
         assert any(e[0] == name for e in worker), name
+    return step_line
+
+
+def test_training_spans_in_the_profile(tmp_path, streamed):
+    _traced_steps(tmp_path, TRAIN_CHILDREN)
+
+
+def test_training_spans_on_the_device_tier(tmp_path):
+    """The tier's step has no reconcile or padding stage; the write-back
+    of the tier's rows when ``fit_stream`` ends runs as ``store.sync``."""
+    step_line = _traced_steps(tmp_path, TIER_CHILDREN)
+    names = {e[0] for e in step_line}
+    assert not names & {"foem.reconcile", "foem.pad_rows"}
+    assert "store.sync" in names
 
 
 def _server(tmp_path):

@@ -73,6 +73,58 @@ def test_dirty_rows_survive_crash_after_flush(tmp_path):
     np.testing.assert_allclose(st2.fetch_rows(np.array([1])), 9.0)
 
 
+@pytest.mark.parametrize("buffer_rows", [0, 4])
+def test_device_tier_is_one_store_with_the_host_tier(tmp_path, buffer_rows):
+    """The device row tier and the host tier read as one store: attach
+    fills the tier from the host (no copy for a store never written), a
+    tier write reaches the host only through a read or flush, a host write
+    reaches the tier, and detach writes back what is left."""
+    import jax.numpy as jnp
+
+    from repro.core.streaming import tier_ids
+
+    K, W = 8, 100
+    fresh = _mk(tmp_path / "fresh", buffer_rows, K, W)
+    assert fresh.attach_tier() == 0
+    fresh.detach_tier()
+
+    st = _mk(tmp_path / "st", buffer_rows, K, W)
+    rng = np.random.default_rng(0)
+    base = rng.random((W, K), dtype=np.float32)
+    st.write_rows(np.arange(W), base)
+    assert st.attach_tier() == W and st.has_tier
+    ids = np.array([5, 17, 3, 60, 9, 88])
+    dev_ids = jnp.asarray(tier_ids(ids, W))
+    got = np.asarray(st.tier_gather(dev_ids))
+    np.testing.assert_array_equal(got[: len(ids)], base[ids])
+    assert not got[len(ids):].any()                  # padding reads zeros
+
+    new = rng.random((len(dev_ids), K), dtype=np.float32)
+    st.take_changed()
+    v = st.write_version
+    assert st.tier_write(ids, dev_ids, jnp.asarray(new)) == v + 1
+    np.testing.assert_array_equal(st._read_rows(ids), base[ids])   # behind
+    np.testing.assert_array_equal(st.take_changed(), np.sort(ids))
+    # a read writes back the dirty rows it covers, and only those
+    np.testing.assert_array_equal(st.fetch_rows(ids[:2]), new[:2])
+    np.testing.assert_array_equal(st._read_rows(ids[2:]), base[ids[2:]])
+    # a host write reaches the tier and is not overwritten by it
+    st.write_rows(ids[2:4], np.full((2, K), 7.0, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(st.tier_gather(dev_ids))[2:4], np.full((2, K), 7.0))
+    want = base.copy()
+    want[ids] = new[: len(ids)]
+    want[ids[2:4]] = 7.0
+    st.flush()
+    back = ParameterStore.attach(str(tmp_path / "st"), K, W)
+    np.testing.assert_array_equal(back._read_rows(np.arange(W)), want)
+    st.tier_write(ids, dev_ids, jnp.asarray(new + 1.0))
+    st.detach_tier()
+    assert not st.has_tier
+    want[ids] = new[: len(ids)] + 1.0
+    np.testing.assert_array_equal(st._read_rows(np.arange(W)), want)
+
+
 def test_vocab_watermark_and_capacity(tmp_path):
     st = _mk(tmp_path)
     st.ensure_vocab(50)

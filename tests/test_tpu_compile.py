@@ -116,3 +116,27 @@ def test_kernel_compiles_for_v5e(one_chip, launch):
     fn, shapes = LAUNCHES[launch]()
     compiled = _compile(fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_device_tier_gather_and_scatter_copy_no_table(one_chip):
+    """The trainer's device row tier at PubMed's widths (W=141,043 rows,
+    K=10^4 padded to the lanes, an 8,704-row step): its gather and its
+    donated scatter need temporaries of about twice the step's rows (0.7
+    GB), not a copy of the 5.7 GB table (6.1 GB, which an unaligned K
+    costs), and the scatter is one operation, not a loop over the ids
+    (which a scatter into part of each row costs)."""
+    from repro.core.streaming import TIER_LANES, _tier_gather, _tier_scatter
+
+    rows, k, n = 141_043, 10_000, 8_704
+    lanes = -(-k // TIER_LANES) * TIER_LANES
+    table = jax.ShapeDtypeStruct((rows, lanes), F32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)
+    new = jax.ShapeDtypeStruct((n, k), F32, sharding=one_chip)
+    quarter = rows * lanes * 4 // 4
+    gather = _tier_gather.lower(table, ids, k).compile().memory_analysis()
+    assert gather.temp_size_in_bytes <= quarter
+    scatter = _tier_scatter.lower(table, ids, new).compile()
+    assert "while" not in scatter.as_text()      # one scatter, not a loop
+    mem = scatter.memory_analysis()
+    assert mem.temp_size_in_bytes <= quarter
+    assert mem.alias_size_in_bytes >= rows * lanes * 4       # in place
