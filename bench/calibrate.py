@@ -62,15 +62,13 @@ def main(argv=None) -> int:
     cell = spec.load_cell(args.workload)
     say = lambda s: print(s, flush=True)
     if args.what == "knee":
-        from foembench import serve_cell
-
         device.enable_compile_cache()
         devices = device.require_chips(cell.chips)
         env = runner.Env(devices, device.CompileCounter(),
                          os.path.join(HERE, "out"), say)
         for rate in [float(r) for r in args.rates.split(",")]:
-            out = serve_cell.run(cell, env, args.seed, args.seconds, False,
-                                 rate=rate)
+            out = cell.runner.run(cell, env, args.seed, args.seconds, False,
+                                  rate=rate)
             for line in out["notes"]:
                 say(line)
             record({"rate": rate, **out["e2e"], "failed": out["failed"],

@@ -1,4 +1,4 @@
-"""One run of one cell: find the chip, run the cell's kind, read its
+"""One run of one cell: find the chip, run the cell's runner, read its
 metrics, decide ``correct``, and build the result line."""
 from __future__ import annotations
 
@@ -23,20 +23,6 @@ class Env:
 
     def memory_peak(self) -> int:
         return device.memory_peak_bytes(self.devices)
-
-
-def _runner(cell: Cell):
-    kind = cell.traffic["kind"]
-    if kind == "stream":
-        from foembench import train_cell
-
-        return train_cell.run
-    if kind == "open_loop":
-        from foembench import serve_cell
-
-        return serve_cell.run
-    raise ValueError(f"traffic mix {cell.traffic['name']!r} has unknown "
-                     f"kind {kind!r}")
 
 
 def read_per_layer(cell: Cell, ctx: Dict) -> Dict[str, Dict]:
@@ -68,8 +54,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         devices = jax.devices()[:cell.chips]
     env = Env(devices, device.CompileCounter(), out_dir, say)
     os.makedirs(out_dir, exist_ok=True)
-    out = _runner(cell)(cell, env, seed, seconds, trace, control=control,
-                        fault=fault, **kw)
+    out = cell.runner.run(cell, env, seed, seconds, trace, control=control,
+                          fault=fault, **kw)
     for line in out["notes"]:
         say(line)
     record = {"platform": devices[0].platform,

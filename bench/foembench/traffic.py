@@ -7,7 +7,8 @@
   minibatches and cycled, plus a fixed held-out set;
 * ``"open_loop"`` — serving requests: bag-of-words documents with
   Zipf-skewed words and uniform token counts, sent at Poisson arrival times
-  at a fixed rate (a share ``load`` of the configuration's measured knee).
+  at a fixed rate (a share ``load`` of the measured knee: the mix's own
+  ``knee_docs_per_s`` where it has one, else the configuration's).
 
 Documents, sizes and arrival gaps are drawn from the mix's ``base_seed``
 and the configuration; the run's seed puts them in another order (and keys
@@ -179,13 +180,18 @@ class Requests:
 
 
 def offered_rate(cfg: dict, mix: dict) -> float:
-    return float(mix["load"]) * float(cfg["serve"]["knee_docs_per_s"])
+    """``load`` times the knee of the mix's deployment (its own
+    ``knee_docs_per_s``, else the configuration's)."""
+    knee = mix.get("knee_docs_per_s")
+    if knee is None:
+        knee = cfg["serve"]["knee_docs_per_s"]
+    return float(mix["load"]) * float(knee)
 
 
 def open_loop(cfg: dict, mix: dict, seed: int, seconds: float,
               rate: float = None) -> Requests:
     """Requests due in the first ``seconds`` of the window (and a margin),
-    at ``rate`` docs/s (default: the mix's load times the config's knee)."""
+    at ``rate`` docs/s (default: :func:`offered_rate`)."""
     rate = offered_rate(cfg, mix) if rate is None else float(rate)
     W = int(cfg["vocab_size"])
     lo, hi = (int(x) for x in mix["doc_tokens"])

@@ -20,7 +20,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from foembench import checks, reference, traffic, work
+from foembench import checks, traffic, work
 from foembench.tracing import Capture
 
 
@@ -229,7 +229,7 @@ def run(cell, env, seed: int, seconds: float, trace: bool, *,
         held = [corpus.heldout.doc(i) for i in range(corpus.heldout.n)]
         vocab_h = np.unique(np.concatenate([w for w, _ in held]))
         rows_h = store.fetch_rows(vocab_h, promote=False)
-        out["e2e"]["train_heldout_ppl"] = reference.heldout_perplexity(
+        out["e2e"]["train_heldout_ppl"] = cell.reference.heldout_perplexity(
             held, {int(w): i for i, w in enumerate(vocab_h)}, rows_h,
             store.phi_k.copy(), vocab=lda.W, alpha_m1=lda.alpha_m1,
             beta_m1=lda.beta_m1, split_seed=int(mix["topics"]["base_seed"]))
@@ -241,19 +241,19 @@ def run(cell, env, seed: int, seconds: float, trace: bool, *,
     steps = []
     for r in check_recs:
         key, sub = jax.random.split(key)
-        steps.append(reference.StepInput(
+        steps.append(cell.reference.StepInput(
             sub, np.searchsorted(view, r.mb.local_vocab), r.mb.local_word_ids,
             r.mb.counts, int(r.metrics.sweeps)))
     kw = dict(vocab=lda.W, alpha_m1=lda.alpha_m1, beta_m1=lda.beta_m1,
               warmup=lda.warmup_sweeps, check_every=lda.ppl_check_every,
               active=lda.active_topics)
     t_ref = time.perf_counter()
-    ref = reference.foem_steps(steps, len(view), lda.K, **kw)
+    ref = cell.reference.foem_steps(steps, len(view), lda.K, **kw)
     if control:
         import jax.numpy as jnp
 
-        low = reference.foem_steps(steps, len(view), lda.K, dtype=jnp.bfloat16,
-                                   **kw)
+        low = cell.reference.foem_steps(steps, len(view), lda.K,
+                                        dtype=jnp.bfloat16, **kw)
         snaps = snaps[:1] + [(p, k.astype(np.float64)) for p, k, _ in low]
         ppl = [q for _, _, q in low]
     else:

@@ -15,10 +15,12 @@ import pytest  # noqa: E402
 PEAKS = {"flops_per_s": 1.97e14, "hbm_bytes_per_s": 8.19e11}
 
 
-def tiny_cell(workload: str):
+def tiny_cell(workload: str, **where):
+    """``workload`` (found as ``spec.load_cell`` finds it, with ``where``'s
+    ``bench``, ``root`` and ``bench_dir``) at a CPU test's size."""
     from foembench import spec
 
-    cell = spec.load_cell(workload)
+    cell = spec.load_cell(workload, **where)
     cell.config.update(vocab_size=300, num_topics=8, minibatch_docs=16,
                        bucket_len=16, active_topics=4, num_docs=16 * 4 + 32,
                        mean_doc_tokens=12, num_tokens=5000, buffer_rows=300)
@@ -34,19 +36,29 @@ def tiny_cell(workload: str):
     else:
         mix.update(doc_tokens=[4, 16], checked_requests=10_000,
                    trace_seconds=0.3)
+        if "knee_docs_per_s" in mix:
+            mix["knee_docs_per_s"] = 200.0
     return cell
 
 
-def run_tiny(workload: str, tmp_path, *, seconds: float = 0.6,
+def run_tiny(workload, tmp_path, *, seconds: float = 0.6,
              trace: bool = False, **kw):
-    """One run of a tiny cell with the look for a chip skipped."""
+    """One run of a tiny cell (a workload's name, or a cell made by
+    :func:`tiny_cell`) with the look for a chip skipped."""
     from foembench import runner
 
-    return runner.run_cell(tiny_cell(workload), 20121029, seconds, trace,
+    cell = tiny_cell(workload) if isinstance(workload, str) else workload
+    kw.setdefault("say", lambda s: None)
+    return runner.run_cell(cell, 20121029, seconds, trace,
                            require_tpu=False, peaks=PEAKS,
-                           out_dir=str(tmp_path), say=lambda s: None, **kw)
+                           out_dir=str(tmp_path), **kw)
 
 
 @pytest.fixture
 def tiny():
     return run_tiny
+
+
+@pytest.fixture
+def tiny_cell_of():
+    return tiny_cell

@@ -84,4 +84,20 @@ def test_new_metrics_are_declared_for_their_cells():
         assert declared[name]["workloads"] == ["kos_k100.train",
                                                "pubmed_k10k.train"]
     for name in SERVE:
-        assert declared[name]["workloads"] == ["kos_k100.serve_poisson"]
+        assert declared[name]["workloads"] == ["kos_k100.serve_poisson",
+                                               "kos_k100.serve_x4"]
+
+
+def test_replica_skew_reader():
+    read = spec.load_reader("serve.replica_skew")
+    # the busiest of 4 replicas took 60 of 160 batches: 1.5x an even 40
+    pool = {"kind": "serve", "batch_log": SERVE_LOG,
+            "dispatch": {0: 60, 1: 50, 2: 30, 3: 20}}
+    assert read(pool) == pytest.approx(50.0)
+    assert read(dict(pool, dispatch={0: 7, 1: 7})) == pytest.approx(0.0)
+    assert read(dict(pool, dispatch={0: 0, 1: 0})) is None
+    assert read({"kind": "serve", "batch_log": SERVE_LOG}) is None
+    assert read({"kind": "train", "steps": TRAIN_STEPS}) is None
+    declared = {m["name"]: m for m in spec.load_benchmark()["per_layer"]}
+    assert declared["serve.replica_skew"]["workloads"] == ["kos_k100.serve_x4"]
+    assert declared["serve.replica_skew"]["layer"] == "replica balancer"

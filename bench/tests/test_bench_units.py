@@ -144,36 +144,55 @@ def test_every_benchmark_cell_resolves():
                                       for m in cell.per_layer)
 
 
-def test_new_config_mix_and_metric_found_by_name(tmp_path):
-    """A later change adds a configuration, a mix and a metric as new files
-    and new entries only; the harness finds them without an edit."""
+def _checkout(tmp_path):
+    """A copy of the benchmark's files under ``tmp_path``, and its
+    ``BENCHMARK.json`` as a dict, for a change to add to."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH_DIR, root / "bench",
                     ignore=shutil.ignore_patterns("out", ".jax_cache",
                                                   "__pycache__"))
     bench = json.loads(open(os.path.join(ROOT, "BENCHMARK.json")).read())
+    return root, bench
+
+
+def _add_config(root, bench, name, **changes):
     cfg = json.loads(open(root / "bench/configs/kos_k100.json").read())
-    cfg.update(name="kos_k50", num_topics=50)
-    (root / "bench/configs/kos_k50.json").write_text(json.dumps(cfg))
+    cfg.update(name=name, **changes)
+    (root / f"bench/configs/{name}.json").write_text(json.dumps(cfg))
+    bench["configs"].append({"name": name, "source": "x",
+                             "file": f"bench/configs/{name}.json",
+                             "reduced": sorted(changes)})
+    return cfg
+
+
+def _add_mix(root, bench, config, name, chips=1, **changes):
     mix = json.loads(open(root / "bench/traffic/serve_poisson.json").read())
-    mix["load"] = 1.2
-    (root / "bench/traffic/serve_overload.json").write_text(json.dumps(mix))
+    mix.update(changes)
+    (root / f"bench/traffic/{name}.json").write_text(json.dumps(mix))
+    bench["workloads"].append({"name": f"{config}.{name}", "config": config,
+                               "traffic": name, "chips": chips, "why": "x"})
+    return f"{config}.{name}"
+
+
+def _load(root, bench, workload):
+    return spec.load_cell(workload, bench, root=str(root),
+                          bench_dir=str(root / "bench"))
+
+
+def test_new_config_mix_and_metric_found_by_name(tmp_path):
+    """A later change adds a configuration, a mix and a metric as new files
+    and new entries only; the harness finds them without an edit."""
+    root, bench = _checkout(tmp_path)
+    cfg = _add_config(root, bench, "kos_k50", num_topics=50)
+    workload = _add_mix(root, bench, "kos_k50", "serve_overload", load=1.2)
     (root / "bench/metrics/serve.launches.py").write_text(
         "def read(ctx):\n    return float(len(ctx['batch_log']))\n")
-    bench["configs"].append({"name": "kos_k50", "source": "x",
-                             "file": "bench/configs/kos_k50.json",
-                             "reduced": ["num_topics"]})
-    bench["workloads"].append({"name": "kos_k50.serve_overload",
-                               "config": "kos_k50",
-                               "traffic": "serve_overload", "chips": 1,
-                               "why": "x"})
     bench["per_layer"].append({"name": "serve.launches", "unit": "launches",
                                "better": "higher", "source": "program_counter",
                                "layer": "serving front",
                                "moves": "serve_docs_per_s",
-                               "workloads": ["kos_k50.serve_overload"]})
-    cell = spec.load_cell("kos_k50.serve_overload", bench, root=str(root),
-                          bench_dir=str(root / "bench"))
+                               "workloads": [workload]})
+    cell = _load(root, bench, workload)
     assert cell.config["num_topics"] == 50
     assert traffic.offered_rate(cell.config, cell.traffic) == pytest.approx(
         1.2 * cfg["serve"]["knee_docs_per_s"])
@@ -186,6 +205,84 @@ def test_unknown_names_are_refused():
         spec.load_cell("no_such.cell")
     with pytest.raises(spec.SpecError):
         spec.load_reader("no.such.metric")
+
+
+def test_default_reference_and_runners_are_the_packages_own():
+    from foembench import reference, serve_cell, train_cell
+
+    runner_of = {"stream": train_cell, "open_loop": serve_cell}
+    for w in spec.load_benchmark()["workloads"]:
+        cell = spec.load_cell(w["name"])
+        assert cell.reference is reference
+        assert cell.runner is runner_of[cell.traffic["kind"]]
+
+
+def test_missing_reference_or_runner_module_is_refused(tmp_path):
+    root, bench = _checkout(tmp_path)
+    _add_config(root, bench, "kos_blocked", reference="reference_blocked")
+    blocked = _add_mix(root, bench, "kos_blocked", "serve_blocked")
+    custom = _add_mix(root, bench, "kos_k100", "serve_custom",
+                      runner="serve_custom")
+    bad = _add_mix(root, bench, "kos_k100", "serve_bad", runner="../run")
+    for workload in (blocked, custom, bad):
+        with pytest.raises(spec.SpecError):
+            _load(root, bench, workload)
+
+
+def test_chips_must_match_the_replicas_served(tmp_path):
+    root, bench = _checkout(tmp_path)
+    four_on_one = _add_mix(root, bench, "kos_k100", "serve_x4_on_one",
+                           replicas=4)
+    one_on_four = _add_mix(root, bench, "kos_k100", "serve_on_four", chips=4)
+    for workload in (four_on_one, one_on_four):
+        with pytest.raises(spec.SpecError):
+            _load(root, bench, workload)
+    _add_mix(root, bench, "kos_k100", "serve_x2", chips=2, replicas=2)
+    assert _load(root, bench, "kos_k100.serve_x2").chips == 2
+
+
+def test_mix_knee_comes_before_the_configurations():
+    cfg = _cfg()
+    mix = dict(load=0.5)
+    assert traffic.offered_rate(cfg, mix) == 50.0
+    assert traffic.offered_rate(cfg, dict(mix, knee_docs_per_s=300.0)) == 150.0
+
+
+def test_config_reference_and_mix_runner_run_as_new_files(tmp_path, tiny,
+                                                          tiny_cell_of):
+    """A configuration brings its own reference, and a mix its own runner,
+    as new files under the benchmark directory and new entries in
+    ``BENCHMARK.json``; a run goes through them with no file edited."""
+    root, bench = _checkout(tmp_path)
+    pkg = root / "bench/foembench"
+    (pkg / "reference_blocked.py").write_text(
+        (pkg / "reference.py").read_text()
+        + "\nCALLS = []\n_infer_theta = infer_theta\n\n\n"
+        "def infer_theta(*args, **kw):\n    CALLS.append(len(args[0]))\n"
+        "    return _infer_theta(*args, **kw)\n")
+    shutil.copy(pkg / "serve_cell.py", pkg / "serve_copy.py")
+    _add_config(root, bench, "kos_blocked", reference="reference_blocked")
+    workload = _add_mix(root, bench, "kos_blocked", "serve_copied",
+                        runner="serve_copy")
+    for m in bench["end_to_end"]:
+        if m["name"] in ("serve_p99_ms", "serve_docs_per_s"):
+            m["workloads"].append(workload)
+    cell = tiny_cell_of(workload, bench=bench, root=str(root),
+                     bench_dir=str(root / "bench"))
+    assert cell.reference.__file__ == str(pkg / "reference_blocked.py")
+    assert cell.runner.__file__ == str(pkg / "serve_copy.py")
+    r = tiny(cell, tmp_path / "out")
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["serve_p99_ms"]["value"] > 0
+    assert sum(cell.reference.CALLS) > 0
+    # what was there is untouched: only the new files differ
+    import filecmp
+
+    cmp = filecmp.dircmp(BENCH_DIR, root / "bench",
+                         ignore=["out", ".jax_cache", "__pycache__"])
+    assert not cmp.diff_files
+    assert not filecmp.dircmp(os.path.join(BENCH_DIR, "foembench"),
+                              pkg, ignore=["__pycache__"]).diff_files
 
 
 # ----------------------------------------------------------------- generator
@@ -278,7 +375,8 @@ def test_latency_counts_from_the_due_time_through_a_stall():
     reqs = traffic.open_loop(_cfg(), mix, 1, seconds=1.5, rate=200.0)
     n = int(np.searchsorted(reqs.due, 1.5))
     eng = _StallingEngine(stall_at=0.3, stall=0.6)     # answers held till 0.9
-    res = serve_cell._window(eng, reqs, n, 1.5, _Env(), False, 0.0)
+    res = serve_cell._window(eng, eng.batch_log, reqs, n, 1.5, _Env(), False,
+                            0.0)
     for t in eng._timers:
         t.join(5)
     lat = res["lat"]
